@@ -92,10 +92,10 @@ def _instrument_acting(tr: DistributedTrainer, svc: PropertyService) -> dict:
     # dense entry point + the packed split pair; the sync packed path
     # (fleet_q_values_packed) routes through the instance-patched
     # dispatch/fetch attributes, so wrapping the pair covers it too
-    for pol in (tr._fleet_policy, tr._fleet_policy_sharded):
-        pol.fleet_q_values = timed(pol.fleet_q_values)
-        pol.fleet_q_dispatch_packed = timed(pol.fleet_q_dispatch_packed)
-        pol.fleet_q_fetch = timed(pol.fleet_q_fetch)
+    pol = tr._fleet_policy
+    pol.fleet_q_values = timed(pol.fleet_q_values)
+    pol.fleet_q_dispatch_packed = timed(pol.fleet_q_dispatch_packed)
+    pol.fleet_q_fetch = timed(pol.fleet_q_fetch)
     for view in tr._views:
         view.q_values = timed(view.q_values)
     svc.predict = timed(svc.predict)

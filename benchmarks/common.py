@@ -130,11 +130,9 @@ def services():
     from repro.core import RewardConfig
     from repro.data.datasets import antioxidant_dataset, dataset_property_table, \
         train_test_split
-    from repro.predictors import PropertyService
-    from repro.predictors.training import ensure_trained
+    from repro.predictors.training import trained_service
 
-    bm, bp, im, ip_, metrics = ensure_trained(verbose=False)
-    service = PropertyService(bm, bp, im, ip_)
+    service, metrics = trained_service()
     ds = antioxidant_dataset(600)
     train, test = train_test_split(ds)
     props = dataset_property_table(train)

@@ -150,6 +150,9 @@ def main() -> None:
                          "per-metric delta table and exit (no benches run)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.trajectory:
         print_trajectory(".")
         return

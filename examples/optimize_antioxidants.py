@@ -27,8 +27,7 @@ from repro.core.finetune import fine_tune
 from repro.chem.oracle import oracle_bde, oracle_ip
 from repro.data.datasets import (antioxidant_dataset, dataset_property_table,
                                  train_test_split)
-from repro.predictors import PropertyService
-from repro.predictors.training import ensure_trained
+from repro.predictors.training import trained_service
 
 
 def main() -> None:
@@ -40,8 +39,7 @@ def main() -> None:
     ap.add_argument("--n-test", type=int, default=8)
     args = ap.parse_args()
 
-    bm, bp, im, ipar, _ = ensure_trained()
-    service = PropertyService(bm, bp, im, ipar)
+    service, _ = trained_service(verbose=True)
     ds = antioxidant_dataset(600)
     train, test = train_test_split(ds)
     props = dataset_property_table(train)

@@ -16,16 +16,14 @@ from repro.core import (
 from repro.core.agent import QNetwork
 from repro.core.distributed import DistributedTrainer, greedy_optimize
 from repro.data.datasets import antioxidant_dataset, dataset_property_table
-from repro.predictors import PropertyService
-from repro.predictors.training import ensure_trained
+from repro.predictors.training import trained_service
 
 
 def main() -> None:
     # 1. predictors (Alfabet-S / AIMNet-S), trained against the oracle once
-    bde_model, bde_params, ip_model, ip_params, metrics = ensure_trained()
+    service, metrics = trained_service(verbose=True)
     print(f"predictors ready: BDE rel err {metrics['bde']['rel_err_mean']:.2%}, "
           f"IP rel err {metrics['ip']['rel_err_mean']:.2%}")
-    service = PropertyService(bde_model, bde_params, ip_model, ip_params)
 
     # 2. data + reward normalisation bounds (§3.4)
     mols = antioxidant_dataset(32, seed=9)

@@ -32,10 +32,8 @@ def main() -> None:
     net = QNetwork()
     params = net.init(jax.random.PRNGKey(0))
     if args.trained:
-        from repro.predictors import PropertyService
-        from repro.predictors.training import ensure_trained
-        bm, bp, im, ip_, metrics = ensure_trained()
-        properties = PropertyService(bm, bp, im, ip_)
+        from repro.predictors.training import trained_service
+        properties, metrics = trained_service(verbose=True)
         print(f"predictor accuracy: BDE {metrics['bde']['rel_err_mean']:.2%}, "
               f"IP {metrics['ip']['rel_err_mean']:.2%} (paper: <5%)")
     else:

@@ -18,9 +18,10 @@ become two collective placements:
 Both cross-worker means are implemented as all_gather + an identical
 full-worker-axis reduction on every device (``fleet_mean``) rather than
 ``pmean`` of per-shard means: the reduction order is then independent of
-the mesh size, which is what lets tests/multidevice pin nd > 1 runs
-BIT-identical to the nd = 1 reference (and lets dead mesh-padding workers
-be masked out exactly).  The roofline benchmark quantifies the traffic:
+the mesh size, which is what holds nd > 1 runs to the nd = 1 reference
+within a few float32 ulps (tests/multidevice, under
+``repro.launch.verify.assert_nd_equivalent``) and lets dead mesh-padding
+workers be masked out exactly.  The roofline benchmark quantifies the traffic:
 episode-sync moves (param_bytes) once per episode instead of (grad_bytes x
 updates_per_episode) — the paper's communication-efficiency claim in
 collective-bytes form.
@@ -36,14 +37,17 @@ tests/test_rollout.py:
 
 * ``rollout="per_worker"``      the paper's sequential per-process loop
                                 (W dispatches/step) — kept for comparison;
-* ``rollout="fleet"``           one vmap'd Q dispatch per step (PR-1 path);
-* ``rollout="fleet_sharded"``   the same dispatch through ``shard_map``
-                                over the mesh "data" axis: each device
-                                evaluates only its resident workers'
-                                ``[W/nd, C, D]`` slice under its resident
-                                ``[W/nd, ...]`` params (no collective —
-                                acting is embarrassingly data-parallel);
-* ``rollout="fleet_pipelined"`` the sharded dispatch + the engine's
+* ``rollout="fleet"``           one Q dispatch per step, through
+                                ``shard_map`` over the mesh "data" axis:
+                                each device evaluates only its resident
+                                workers' ``[W/nd, C, D]`` slice under its
+                                resident ``[W/nd, ...]`` params (no
+                                collective — acting is embarrassingly
+                                data-parallel); one device is a one-device
+                                mesh, so every nd runs the same program;
+* ``rollout="fleet_sharded"``   a second name for ``"fleet"`` (the same
+                                compiled dispatch);
+* ``rollout="fleet_pipelined"`` the same dispatch + the engine's
                                 double-buffered step: step t+1's candidate
                                 enumeration/fingerprinting overlaps step
                                 t's property batch (the 512-worker path).
@@ -86,6 +90,7 @@ from dataclasses import dataclass, field, replace
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.chem.chemcache import ChemCache
@@ -103,21 +108,6 @@ from repro.launch.mesh import fleet_sharding, make_host_mesh, padded_worker_coun
 from repro.optim import adam
 from repro.optim.adam import apply_updates
 from repro.predictors.service import PropertyService
-
-try:  # jax >= 0.5: public API, replication check kwarg renamed to check_vma
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool | None = None):
-    kwargs = {}
-    if check_rep is not None:
-        kwargs[_SHARD_MAP_CHECK_KW] = check_rep
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
 
 ROLLOUT_MODES = ("fleet", "fleet_sharded", "fleet_pipelined", "per_worker")
 _FLEET_MODES = ("fleet", "fleet_sharded", "fleet_pipelined")
@@ -221,9 +211,9 @@ class _FleetView:
     high-water mark: capacity only ever grows, and the jit always sees the
     full buffer, so shapes change O(log C) times per run instead of every
     time the per-step max drifts — the property that keeps W=512 free of
-    per-step recompiles.  With ``sharded=True`` the dispatch goes through
-    the ``shard_map`` fleet fn with the batch placed on the mesh's "data"
-    axis next to the (already-sharded) parameters.
+    per-step recompiles.  Every batch is placed on the mesh's "data" axis
+    (``fleet_sharding``) next to the already-sharded parameters before the
+    one ``shard_map`` dispatch of its representation.
 
     ``acting`` picks the batch representation (``ACTING_MODES``): the
     dense f32 reference, or the packed u8 bit planes (optionally with the
@@ -231,10 +221,8 @@ class _FleetView:
     dense f32 candidate buffer on the host.
     """
 
-    def __init__(self, trainer: "DistributedTrainer", sharded: bool = False,
-                 acting: str = "dense"):
+    def __init__(self, trainer: "DistributedTrainer", acting: str = "dense"):
         self.t = trainer
-        self.sharded = sharded
         self.acting = acting
         # engine-facing protocol switches (see rollout.FleetPolicy)
         self.wants_packed_states = acting != "dense"
@@ -283,11 +271,8 @@ class _FleetView:
             dense[w, x.shape[0]:] = 0.0  # clear rows left by the last step
         self.t.n_q_dispatches += 1
         self.t.acting_h2d_bytes += dense.nbytes
-        if self.sharded:
-            x = jax.device_put(dense, self.t._fleet_in_sharding)
-            q = np.asarray(self.t._fleet_q_sharded(self.t.params, x))
-        else:
-            q = np.asarray(self.t._fleet_q(self.t.params, jnp.asarray(dense)))
+        x = jax.device_put(dense, self.t._fleet_in_sharding)
+        q = np.asarray(self.t._fleet_q(self.t.params, x))
         return [q[w, :n] for w, n in enumerate(counts)]
 
     # ---- packed protocol (rollout.FleetPolicy) ------------------- #
@@ -310,13 +295,8 @@ class _FleetView:
             frac[w, n:] = 0.0
         self.t.n_q_dispatches += 1
         self.t.acting_h2d_bytes += bits.nbytes + frac.nbytes
-        if self.sharded:
-            xb = jax.device_put(bits, self.t._fleet_in_sharding)
-            xf = jax.device_put(frac, self.t._fleet_in_sharding)
-            q = self.t._fleet_q_packed_sharded(self.t.params, xb, xf)
-        else:
-            q = self.t._fleet_q_packed(
-                self.t.params, jnp.asarray(bits), jnp.asarray(frac))
+        xb, xf = jax.device_put((bits, frac), self.t._fleet_in_sharding)
+        q = self.t._fleet_q_packed(self.t.params, xb, xf)
         return q, counts
 
     def fleet_q_fetch(self, handle) -> list[np.ndarray]:
@@ -336,6 +316,49 @@ class _FleetView:
 
     def select_action(self, q: np.ndarray, worker: int) -> int:
         return self.t._select_action(q, worker)
+
+
+def fleet_q_dispatches(net: QNetwork, mesh: Mesh, *,
+                       use_pallas_qnet: bool = False):
+    """The fleet acting dispatches, one per batch representation:
+    ``(dense, packed)``.
+
+    ``dense(params, x)`` takes ``[W, C, D]`` f32 states, ``packed(params,
+    bits, frac)`` the ``[W, C, FP_BITS/8]`` u8 planes and ``[W, C]`` f32
+    steps-left (unpacked INSIDE the jit, ~32x less acting H2D traffic);
+    both return q ``[W, C]`` under the stacked ``[W, ...]`` params — ONE
+    dispatch per environment step regardless of n_workers.  Both run
+    through ``shard_map`` over the mesh's "data" axis: each device
+    evaluates its resident ``[W/nd, ...]`` slice with no collective, and
+    one device is a one-device mesh.  Every operand must be placed on
+    ``fleet_sharding(mesh)``.  ``out_shardings`` is pinned like the update
+    fns: at nd > 1 the compiler may otherwise mark the output replicated,
+    and the flip retraces the dispatch (the recompile counter gates this).
+    With ``use_pallas_qnet`` the packed evaluation routes through the
+    stacked bit-plane kernel, compiled when the mesh is made of TPU
+    devices and the XLA unpack path otherwise."""
+    spec_w = P("data")
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
+
+    def packed_body(params, bits, frac):
+        if use_pallas_qnet:
+            from repro.kernels.packed_qnet.ops import packed_qnet_stacked
+            return packed_qnet_stacked(params, bits, frac,
+                                       impl="pallas" if on_tpu else "xla",
+                                       interpret=False)
+        return net.apply_stacked_packed(params, bits, frac)
+
+    out_w = NamedSharding(mesh, spec_w)
+    dense = jax.jit(shard_map(
+        net.apply_stacked, mesh=mesh,
+        in_specs=(spec_w, spec_w), out_specs=spec_w), out_shardings=out_w)
+    # check_vma off: a pallas_call's output carries no varying-axes
+    # annotation, and acting has no collective for the check to guard
+    packed = jax.jit(shard_map(
+        packed_body, mesh=mesh,
+        in_specs=(spec_w, spec_w, spec_w), out_specs=spec_w,
+        check_vma=False), out_shardings=out_w)
+    return dense, packed
 
 
 class DistributedTrainer:
@@ -479,23 +502,28 @@ class DistributedTrainer:
         self.acting_h2d_bytes = 0  # host->device bytes shipped by fleet Q batches
         self._sampler_pool: ThreadPoolExecutor | None = None  # packed_pipelined
 
-        # stacked per-worker params [W_pad, ...] sharded over "data"
+        # stacked per-worker params [W_pad, ...] sharded over "data".  All
+        # workers start from worker 0's weights (like DDP broadcast);
+        # padding rows replicate them too, so the initial stacked tree is
+        # independent of how far the mesh padded the fleet.  The stack and
+        # the Adam moments are built by one jit straight into their
+        # sharded placement: at W=512 the whole stacked state (~22 GB at
+        # paper widths) does not fit on the one device an eager build
+        # would use.
         keys = jax.random.split(jax.random.PRNGKey(cfg.seed), W)
-        params = jax.vmap(self.network.init)(keys)
-        # all workers start from the same weights (like DDP broadcast);
-        # padding rows replicate worker 0's weights too, so the initial
-        # stacked tree is independent of how far the mesh padded the fleet
-        params = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(
-                x[0], (self.n_padded_workers,) + x.shape[1:]), params)
+        p0 = jax.tree_util.tree_map(
+            lambda x: x[0], jax.vmap(self.network.init)(keys[:1]))
         self.opt = adam(cfg.dqn.lr, clip_norm=cfg.dqn.grad_clip)
-        opt_state = jax.vmap(self.opt.init)(params)
+        W_pad = self.n_padded_workers
 
-        shard = lambda tree: jax.device_put(
-            tree, NamedSharding(self.mesh, P("data")))
-        self.params = jax.tree_util.tree_map(shard, params)
+        def stack(p0):
+            params = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x, (W_pad,) + x.shape), p0)
+            return params, jax.vmap(self.opt.init)(params)
+
+        self.params, self.opt_state = jax.jit(
+            stack, out_shardings=fleet_sharding(self.mesh))(p0)
         self.target_params = jax.tree_util.tree_map(jnp.copy, self.params)
-        self.opt_state = jax.tree_util.tree_map(shard, opt_state)
 
         self.epsilon = cfg.dqn.epsilon_initial
         self.episode = 0
@@ -507,8 +535,6 @@ class DistributedTrainer:
         self._views = [_WorkerView(self, w) for w in range(W)]
         self._fleet_in_sharding = fleet_sharding(self.mesh)
         self._fleet_policy = _FleetView(self, acting=cfg.acting)
-        self._fleet_policy_sharded = _FleetView(self, sharded=True,
-                                                acting=cfg.acting)
         self._build_fns()
 
     @property
@@ -587,11 +613,12 @@ class DistributedTrainer:
         def scan_workers(f, xs):
             """Map ``f`` over the shard's resident workers via ``lax.scan``
             instead of ``vmap``: the per-iteration program is independent of
-            W_local, which is what makes the update bit-identical across
-            mesh sizes.  (A vmap'd per-worker matmul lowers as a BATCHED
-            dot, and XLA lowers batch 1 — one worker per device, nd == W —
-            differently from batch n, drifting the gradients' last bits
-            between nd = 1 and nd = W; pinned by tests/multidevice.)"""
+            W_local, which keeps the update within a few ulps across mesh
+            sizes.  (A vmap'd per-worker matmul lowers as a BATCHED dot,
+            and XLA lowers batch 1 — one worker per device, nd == W —
+            differently from batch n.)  XLA still compiles the loop per
+            shard width, and on jax 0.9 XLA-CPU that alone moves a few
+            parameter ulps at nd = 4 (``repro.launch.verify.ND_RTOL``)."""
             def step(carry, x):
                 return carry, f(*x)
             return jax.lax.scan(step, None, xs)[1]
@@ -658,7 +685,7 @@ class DistributedTrainer:
             ddp_update_body, mesh=mesh,
             in_specs=(spec_w, spec_w, spec_w, spec_w),
             out_specs=(spec_w, spec_w, spec_w, spec_w),
-            check_rep=False,
+            check_vma=False,
         ), out_shardings=out_w)
         self._local_update_packed = jax.jit(shard_map(
             local_update_packed_body, mesh=mesh,
@@ -669,7 +696,7 @@ class DistributedTrainer:
             ddp_update_packed_body, mesh=mesh,
             in_specs=(spec_w, spec_w, spec_w, spec_w),
             out_specs=(spec_w, spec_w, spec_w, spec_w),
-            check_rep=False,
+            check_vma=False,
         ), out_shardings=out_w)
         self._sync = jax.jit(shard_map(
             sync_body, mesh=mesh, in_specs=(spec_w,), out_specs=spec_w,
@@ -681,39 +708,8 @@ class DistributedTrainer:
             return net.apply(p, states)
         self._q_one = q_one
 
-        # fleet acting: [W, C, D] states under the stacked [W, ...] params,
-        # per-worker parameter selection via the vmap'd apply — ONE dispatch
-        # per environment step regardless of n_workers
-        self._fleet_q = jax.jit(net.apply_stacked)
-
-        # the same dispatch sharded over "data": each device evaluates its
-        # resident [W/nd, C, D] slice under its resident [W/nd, ...] params;
-        # acting is embarrassingly data-parallel, so there is no collective.
-        # out_shardings is pinned like the update fns: at nd > 1 the
-        # compiler may otherwise mark the output replicated, and the flip
-        # retraces the dispatch (the recompile counter gates this)
-        self._fleet_q_sharded = jax.jit(shard_map(
-            net.apply_stacked, mesh=mesh,
-            in_specs=(spec_w, spec_w), out_specs=spec_w,
-        ), out_shardings=out_w)
-
-        # packed twins of the two fleet dispatches: [W, C, FP_BITS/8] u8
-        # planes + [W, C] f32 steps-left, unpacked INSIDE the jit (~32x
-        # less acting H2D traffic).  With use_pallas_qnet the evaluation
-        # routes through the stacked bit-plane kernel (pallas on TPU;
-        # unpack-in-jit XLA math everywhere else — identical bits to
-        # apply_stacked on the densified input either way)
-        def fleet_q_packed_body(params, bits, frac):
-            if cfg.dqn.use_pallas_qnet:
-                from repro.kernels.packed_qnet.ops import packed_qnet_stacked
-                return packed_qnet_stacked(params, bits, frac)
-            return net.apply_stacked_packed(params, bits, frac)
-
-        self._fleet_q_packed = jax.jit(fleet_q_packed_body)
-        self._fleet_q_packed_sharded = jax.jit(shard_map(
-            fleet_q_packed_body, mesh=mesh,
-            in_specs=(spec_w, spec_w, spec_w), out_specs=spec_w,
-        ), out_shardings=out_w)
+        self._fleet_q, self._fleet_q_packed = fleet_q_dispatches(
+            net, mesh, use_pallas_qnet=cfg.dqn.use_pallas_qnet)
 
     # ------------------------------------------------------------ #
     # training
@@ -757,8 +753,8 @@ class DistributedTrainer:
 
         The fleet modes drive the RolloutEngine: all workers advance in
         lockstep with one Q dispatch + one property batch per step
-        ("fleet_sharded" dispatches through shard_map, "fleet_pipelined"
-        additionally overlaps next-step chemistry with the property batch).
+        ("fleet_pipelined" additionally overlaps next-step chemistry with
+        the property batch).
         ``rollout="per_worker"`` replays the paper's sequential per-process
         loop.  All paths draw from the same per-worker RNG streams, so
         they produce identical transitions (tests/test_rollout.py).
@@ -772,7 +768,7 @@ class DistributedTrainer:
                 self._dataset_stream.draw(W * self.cfg.mols_per_worker))
         if self.cfg.rollout in _FLEET_MODES:
             flat = self.engine.run_episode(
-                self._active_fleet_view, self.service, self.reward_cfg,
+                self._fleet_policy, self.service, self.reward_cfg,
                 self.buffers, pipelined=self.cfg.rollout == "fleet_pipelined")
             records: list[list[StepRecord]] = [[] for _ in range(W)]
             for r in flat:
@@ -807,18 +803,11 @@ class DistributedTrainer:
         self.start_log.append(tuple(m.iso_key() for m in self.molecules))
 
     @property
-    def _active_fleet_view(self) -> _FleetView:
-        """The fleet policy the configured rollout mode dispatches through
-        (the sharded view for both sharded and pipelined modes)."""
-        return self._fleet_policy if self.cfg.rollout == "fleet" \
-            else self._fleet_policy_sharded
-
-    @property
     def candidate_capacity(self) -> int:
         """Current dense candidate-axis capacity of the active fleet view
         (0 until the first dispatch or ``reserve_candidates``)."""
         return 0 if self.cfg.rollout == "per_worker" \
-            else self._active_fleet_view._cap
+            else self._fleet_policy._cap
 
     def reserve_candidates(self, max_candidates: int) -> None:
         """Pre-grow the fleet views' dense candidate capacity (ladder-
@@ -829,7 +818,7 @@ class DistributedTrainer:
         per_worker path, which buckets per worker instead)."""
         if self.cfg.rollout == "per_worker":
             return
-        view = self._active_fleet_view
+        view = self._fleet_policy
         before = view._cap
         view.reserve(max_candidates)
         if view._cap != before:
@@ -1099,8 +1088,7 @@ class DistributedTrainer:
             bytes(np.asarray(flat["meta/start_log"], np.uint8)).decode())]
         for w in range(len(self._worker_rngs)):
             self._worker_rngs[w] = rng_state_from_array(flat[f"rng/worker_{w}"])
-        shard = lambda x: jax.device_put(
-            x, NamedSharding(self.mesh, P("data")))
+        shard = lambda x: jax.device_put(x, fleet_sharding(self.mesh))
         for name, attr in (("params", "params"), ("target", "target_params"),
                            ("opt", "opt_state")):
             live = getattr(self, attr)
@@ -1115,7 +1103,8 @@ class DistributedTrainer:
                     raise CheckpointError(
                         f"leaf {key!r}: checkpoint shape {arr.shape} != "
                         f"live shape {tuple(ref.shape)}")
-                leaves.append(shard(jnp.asarray(arr, dtype=ref.dtype)))
+                # host -> shards directly: no full copy on one device
+                leaves.append(shard(arr.astype(ref.dtype, copy=False)))
             setattr(self, attr, jax.tree_util.tree_unflatten(treedef, leaves))
         for w, buf in enumerate(self.buffers):
             prefix = f"replay/{w}/"

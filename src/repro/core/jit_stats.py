@@ -48,7 +48,7 @@ class RecompileCounter:
     def install(cls) -> "RecompileCounter":
         if cls._instance is None:
             inst = cls()
-            # listeners cannot be unregistered on jax 0.4.x, hence singleton
+            # one process-wide listener, registered once: a singleton
             jax.monitoring.register_event_listener(inst._on_event)
             cls._instance = inst
         return cls._instance

@@ -7,46 +7,63 @@ modification batch — thousands of rows through the MolDQN MLP
 kernels with HBM round-trips for each intermediate; this kernel keeps ALL
 weights plus one row-block resident in VMEM and fuses the whole forward:
 
-  VMEM budget (f32): W1 8.0 MiB + W2 2.0 MiB + W3/W4/W5 <0.3 MiB
-                     + x block (128 x 2049) 1.0 MiB + h 0.5 MiB  ~= 12 MiB
+Block shapes follow the TPU's (8, 128) tiling: biases are ``[1, H]`` rows,
+and the output is a lane-dense ``[1, N]`` row written in ``(1, row_block)``
+tiles (the last layer is computed as ``w5^T @ h^T``).  Every matmul asks
+for ``Precision.HIGHEST`` (float32 on the MXU).
 
-Grid = (row blocks,): one pass over HBM for x, one output write — the
-arithmetic-intensity fix for a memory-bound MLP (see EXPERIMENTS.md §Perf).
-Row blocks of 128 keep the MXU M-dim aligned.
+  VMEM budget (f32, paper widths), every input block double-buffered by
+  the Pallas pipeline:
+      weights  W1 (2049 rows) 8.0 MiB + W2 2.0 MiB + W3 0.25 MiB
+               + W4/W5/biases (lane-padded) < 0.2 MiB    ~= 10.3 MiB
+      x2 (double buffering)                              ~= 20.6 MiB
+      x block (128 x 2049, lane-padded to 2176) 1.06 MiB x2 ~= 2.1 MiB
+      intermediates (h1 0.5 MiB, h2..h4)                 <  1 MiB
+                                                         ~= 24 MiB
+  which is over v5e's 16 MiB default scoped-VMEM limit, so the kernel
+  raises it to ``VMEM_LIMIT_BYTES`` (v5e has 128 MiB of VMEM per core).
+
+Grid = (row blocks,): one pass over HBM for x, one output write.  Row
+blocks of 128 keep the MXU M-dim aligned.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 ROW_BLOCK = 128
+VMEM_LIMIT_BYTES = 48 * 2**20
 
 
-def _qnet_kernel(x_ref, w1, b1, w2, b2, w3, b3, w4, b4, w5, b5, out_ref):
+def _mm(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST)
+
+
+def _qnet_kernel(x_ref, w1, b1, w2, b2, w3, b3, w4, b4, w5t, b5, out_ref):
     h = x_ref[...].astype(jnp.float32)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w1[...], (((1,), (0,)), ((), ()))) + b1[...], 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w2[...], (((1,), (0,)), ((), ()))) + b2[...], 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w3[...], (((1,), (0,)), ((), ()))) + b3[...], 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w4[...], (((1,), (0,)), ((), ()))) + b4[...], 0.0)
-    q = jax.lax.dot_general(h, w5[...], (((1,), (0,)), ((), ()))) + b5[...]
-    out_ref[...] = q[:, 0].astype(out_ref.dtype)
+    h = jnp.maximum(_mm(h, w1[...]) + b1[...], 0.0)
+    h = jnp.maximum(_mm(h, w2[...]) + b2[...], 0.0)
+    h = jnp.maximum(_mm(h, w3[...]) + b3[...], 0.0)
+    h = jnp.maximum(_mm(h, w4[...]) + b4[...], 0.0)
+    # [1, H4] x [rows, H4]^T -> lane-dense [1, rows]
+    q = jax.lax.dot_general(w5t[...], h, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST) + b5[...]
+    out_ref[...] = q.astype(out_ref.dtype)
 
 
 def fused_qnet_rows(
     x: jnp.ndarray,            # [N, in_dim]
-    weights: list[tuple[jnp.ndarray, jnp.ndarray]],   # [(w, b)] x5
+    weights: list[tuple[jnp.ndarray, jnp.ndarray]],   # [(w, b [1, out])] x5,
+                               # the last w transposed to [1, H4]
     *,
     row_block: int = ROW_BLOCK,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """-> q ``[1, N]``."""
     N, in_dim = x.shape
     assert len(weights) == 5, "fused kernel is specialised to the MolDQN 5-layer MLP"
     row_block = min(row_block, N)
@@ -64,7 +81,9 @@ def fused_qnet_rows(
         _qnet_kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((row_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), x.dtype),
+        out_specs=pl.BlockSpec((1, row_block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_qnet_rows",
     )(x, *flat_w)

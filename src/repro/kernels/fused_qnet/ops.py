@@ -18,10 +18,11 @@ def _on_tpu() -> bool:
 def fused_qnet(params: dict, x: jnp.ndarray, *, interpret: bool | None = None) -> jnp.ndarray:
     """params: QNetwork pytree ({"layers": [{"w","b"}, ...x5]}); x [N, 2049]."""
     interpret = (not _on_tpu()) if interpret is None else interpret
-    weights = [(l["w"], l["b"]) for l in params["layers"]]
+    weights = [(l["w"], l["b"][None, :]) for l in params["layers"]]
+    weights[-1] = (weights[-1][0].T, weights[-1][1])
     n = x.shape[0]
     padded = ((n + ROW_BLOCK - 1) // ROW_BLOCK) * ROW_BLOCK
     if padded != n:
         x = jnp.concatenate([x, jnp.zeros((padded - n, x.shape[1]), x.dtype)])
     q = fused_qnet_rows(x, weights, interpret=interpret)
-    return q[:n]
+    return q[0, :n]

@@ -33,16 +33,27 @@ def pack_w1(w1: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return wbits, w1[FP_BITS:]
 
 
+def kernel_weights(params: dict):
+    """QNetwork pytree -> the row kernel's operands ``(w1r, w1f, b1,
+    tail)``: W1 packed into bit-plane slices, every bias a ``[1, H]`` row,
+    and the last layer's weight transposed to ``[1, H4]`` (the kernel
+    writes lane-dense ``[1, rows]`` output tiles)."""
+    weights = [(l["w"], l["b"][None, :]) for l in params["layers"]]
+    w1r, w1f = pack_w1(weights[0][0])
+    tail = weights[1:-1] + [(weights[-1][0].T, weights[-1][1])]
+    return w1r, w1f, weights[0][1], tail
+
+
 @partial(jax.jit, static_argnames=("impl", "interpret"))
 def packed_qnet(params: dict, bits: jnp.ndarray, frac: jnp.ndarray, *,
                 impl: str | None = None, interpret: bool | None = None) -> jnp.ndarray:
     """params: QNetwork pytree ({"layers": [{"w","b"}, ...x5]});
     bits u8 [N, FP_BITS/8]; frac f32 [N] -> q f32 [N]."""
-    weights = [(l["w"], l["b"]) for l in params["layers"]]
     if impl is None:
         impl = "pallas" if _on_tpu() else "xla"
     if impl == "xla":
-        return packed_qnet_ref(bits, frac, weights)
+        return packed_qnet_ref(
+            bits, frac, [(l["w"], l["b"]) for l in params["layers"]])
     interpret = (not _on_tpu()) if interpret is None else interpret
     n = bits.shape[0]
     padded = ((n + ROW_BLOCK - 1) // ROW_BLOCK) * ROW_BLOCK
@@ -50,10 +61,10 @@ def packed_qnet(params: dict, bits: jnp.ndarray, frac: jnp.ndarray, *,
         bits = jnp.concatenate(
             [bits, jnp.zeros((padded - n, bits.shape[1]), bits.dtype)])
         frac = jnp.concatenate([frac, jnp.zeros((padded - n,), frac.dtype)])
-    w1r, w1f = pack_w1(weights[0][0])
+    w1r, w1f, b1, tail = kernel_weights(params)
     q = packed_qnet_rows(bits, frac[:, None].astype(jnp.float32), w1r, w1f,
-                         weights[0][1], weights[1:], interpret=interpret)
-    return q[:n]
+                         b1, tail, interpret=interpret)
+    return q[0, :n]
 
 
 @partial(jax.jit, static_argnames=("impl", "interpret"))
@@ -63,10 +74,10 @@ def packed_qnet_stacked(params: dict, bits: jnp.ndarray, frac: jnp.ndarray, *,
     """The fleet-acting shape: params is a STACKED QNetwork pytree (leaves
     ``[W, ...]``, one tree per worker); bits u8 [W, C, FP_BITS/8]; frac f32
     [W, C] -> q f32 [W, C] — the packed twin of ``QNetwork.apply_stacked``."""
-    weights = [(l["w"], l["b"]) for l in params["layers"]]
     if impl is None:
         impl = "pallas" if _on_tpu() else "xla"
     if impl == "xla":
+        weights = [(l["w"], l["b"]) for l in params["layers"]]
         return jax.vmap(packed_qnet_ref, in_axes=(0, 0, 0))(bits, frac, weights)
     interpret = (not _on_tpu()) if interpret is None else interpret
     n = bits.shape[1]
@@ -75,9 +86,9 @@ def packed_qnet_stacked(params: dict, bits: jnp.ndarray, frac: jnp.ndarray, *,
         pad = ((0, 0), (0, padded - n), (0, 0))
         bits = jnp.pad(bits, pad)
         frac = jnp.pad(frac, pad[:2])
-    # vmap'd pack_w1: per-worker bit-plane slices [W, 8, FP_BITS/8, H1]
-    w1r, w1f = jax.vmap(pack_w1)(weights[0][0])
+    # per-worker kernel operands: w1r [W, 8, FP_BITS/8, H1], biases
+    # [W, 1, H], last weight [W, 1, H4]
+    w1r, w1f, b1, tail = jax.vmap(kernel_weights)(params)
     q = packed_qnet_stacked_rows(bits, frac[..., None].astype(jnp.float32),
-                                 w1r, w1f, weights[0][1], weights[1:],
-                                 interpret=interpret)
-    return q[:, :n]
+                                 w1r, w1f, b1, tail, interpret=interpret)
+    return q[:, 0, :n]

@@ -20,13 +20,32 @@ and the 8 bit-plane matmuls accumulate —
 which is algebraically the dense ``x @ W1`` with the 2048-term reduction
 re-associated into 8 x 256 (hence the 1e-5 parity tolerance vs the dense
 reference instead of bit equality).  Layers 2..5 are then fused exactly as
-in ``fused_qnet``.
+in ``fused_qnet``; the last layer is computed as ``w5^T @ h^T`` so the
+output row block is lane-dense (``[1, rows]``).  Every matmul asks for
+``Precision.HIGHEST`` (float32 on the MXU), so the kernel is held to the
+float32 reference at 1e-5 on the chip as in interpret mode.
 
-  VMEM budget (f32): w1r 8.0 MiB + W2 2.0 MiB + W3/W4/W5 <0.3 MiB
-                     + packed x block (128 x 256 u8) 32 KiB + h 0.5 MiB
-                     ~= 11 MiB
+Block shapes follow the TPU's (8, 128) tiling: every bias is a 2-D
+``[1, H]`` row (``[W, 1, H]`` stacked), and the output is ``[1, N]``
+(``[W, 1, N]`` stacked) written in ``(1, row_block)`` tiles, so the last
+two dims of every block are either (8, 128)-aligned or the full array dims.
 
-Grid = (row blocks,): one packed pass over HBM for x, one output write.
+  VMEM budget (f32, paper widths), every input block double-buffered by
+  the Pallas pipeline:
+      weights  w1r 8.0 MiB + W2 2.0 MiB + W3 0.25 MiB + W4/W5/biases
+               (lane-padded) < 0.2 MiB                    ~= 10.4 MiB
+      x2 (double buffering)                               ~= 20.9 MiB
+      row blocks (bits 32 KiB, frac lane-padded 64 KiB, out) x2 < 0.3 MiB
+      intermediates (h1 0.5 MiB, bit planes, h2..h4)      <  2 MiB
+                                                          ~= 23 MiB
+  (the v5e compiler allocates 20.6 MiB of scoped VMEM for the stacked
+  kernel at W=64, C=128), over v5e's 16 MiB default scoped-VMEM limit, so
+  the kernels raise it to ``VMEM_LIMIT_BYTES`` (v5e has 128 MiB of VMEM
+  per core).
+
+Grid = (row blocks,) for ``packed_qnet_rows`` and (workers, row blocks) for
+``packed_qnet_stacked_rows``: one packed pass over HBM for x, one output
+write.
 """
 
 from __future__ import annotations
@@ -34,30 +53,45 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 ROW_BLOCK = 128
+VMEM_LIMIT_BYTES = 48 * 2**20
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=_HIGHEST)
+
+
+def _qnet_body(bits_u8, frac, w1r, w1f, b1, w2, b2, w3, b3, w4, b4, w5t, b5):
+    """One row block through the MLP: ``bits_u8 [rows, 256]``, ``frac
+    [rows, 1]`` -> q ``[1, rows]`` (lane-dense).  ``w5t`` is the last
+    layer's ``[1, H4]`` transposed weight."""
+    bytes32 = bits_u8.astype(jnp.int32)
+    h = _mm(frac.astype(jnp.float32), w1f) + b1
+    for k in range(8):                                   # np.unpackbits order:
+        plane = ((bytes32 >> (7 - k)) & 1).astype(jnp.float32)  # bit k = MSB-k
+        h = h + _mm(plane, w1r[k])
+    h = jnp.maximum(h, 0.0)
+    h = jnp.maximum(_mm(h, w2) + b2, 0.0)
+    h = jnp.maximum(_mm(h, w3) + b3, 0.0)
+    h = jnp.maximum(_mm(h, w4) + b4, 0.0)
+    # [1, H4] x [rows, H4]^T -> [1, rows]
+    q = jax.lax.dot_general(w5t, h, (((1,), (1,)), ((), ())),
+                            precision=_HIGHEST)
+    return q + b5
 
 
 def _packed_qnet_kernel(bits_ref, frac_ref, w1r, w1f, b1,
-                        w2, b2, w3, b3, w4, b4, w5, b5, out_ref):
-    # unpack-on-the-fly: 8 bit-plane matmuls accumulate layer 1 on the MXU
-    bytes32 = bits_ref[...].astype(jnp.int32)            # [rows, 256]
-    frac = frac_ref[...].astype(jnp.float32)             # [rows, 1]
-    h = jax.lax.dot_general(
-        frac, w1f[...], (((1,), (0,)), ((), ()))) + b1[...]
-    for k in range(8):                                   # np.unpackbits order:
-        plane = ((bytes32 >> (7 - k)) & 1).astype(jnp.float32)  # bit k = MSB-k
-        h = h + jax.lax.dot_general(
-            plane, w1r[...][k], (((1,), (0,)), ((), ())))
-    h = jnp.maximum(h, 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w2[...], (((1,), (0,)), ((), ()))) + b2[...], 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w3[...], (((1,), (0,)), ((), ()))) + b3[...], 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w4[...], (((1,), (0,)), ((), ()))) + b4[...], 0.0)
-    q = jax.lax.dot_general(h, w5[...], (((1,), (0,)), ((), ()))) + b5[...]
-    out_ref[...] = q[:, 0]
+                        w2, b2, w3, b3, w4, b4, w5t, b5, out_ref):
+    out_ref[...] = _qnet_body(
+        bits_ref[...], frac_ref[...], w1r[...], w1f[...], b1[...],
+        w2[...], b2[...], w3[...], b3[...], w4[...], b4[...],
+        w5t[...], b5[...])
 
 
 def packed_qnet_rows(
@@ -65,12 +99,14 @@ def packed_qnet_rows(
     frac: jnp.ndarray,         # f32 [N, 1] steps-left feature column
     w1r: jnp.ndarray,          # f32 [8, FP_BITS/8, H1] bit-plane slices of W1
     w1f: jnp.ndarray,          # f32 [1, H1] the steps-left row of W1
-    b1: jnp.ndarray,           # f32 [H1]
-    tail: list[tuple[jnp.ndarray, jnp.ndarray]],  # [(w, b)] layers 2..5
+    b1: jnp.ndarray,           # f32 [1, H1]
+    tail: list[tuple[jnp.ndarray, jnp.ndarray]],  # [(w, b [1, out])] layers
+                               # 2..5; the last w transposed to [1, H4]
     *,
     row_block: int = ROW_BLOCK,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """-> q f32 ``[1, N]``."""
     N, n_bytes = bits.shape
     assert len(tail) == 4, "packed kernel is specialised to the MolDQN 5-layer MLP"
     row_block = min(row_block, N)
@@ -92,34 +128,22 @@ def packed_qnet_rows(
         _packed_qnet_kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((row_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.float32),
+        out_specs=pl.BlockSpec((1, row_block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, N), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="packed_qnet_rows",
     )(bits, frac, *flat_w)
 
 
 def _packed_qnet_stacked_kernel(bits_ref, frac_ref, w1r, w1f, b1,
-                                w2, b2, w3, b3, w4, b4, w5, b5, out_ref):
+                                w2, b2, w3, b3, w4, b4, w5t, b5, out_ref):
     # one (worker, row-block) grid cell: every ref carries a leading
-    # singleton worker axis — squeeze it and run the row kernel's math
+    # singleton worker axis — index it away and run the row kernel's math
     # under THIS worker's parameter slices
-    bytes32 = bits_ref[0].astype(jnp.int32)              # [rows, 256]
-    frac = frac_ref[0].astype(jnp.float32)               # [rows, 1]
-    h = jax.lax.dot_general(
-        frac, w1f[0], (((1,), (0,)), ((), ()))) + b1[0]
-    for k in range(8):                                   # np.unpackbits order:
-        plane = ((bytes32 >> (7 - k)) & 1).astype(jnp.float32)  # bit k = MSB-k
-        h = h + jax.lax.dot_general(
-            plane, w1r[0][k], (((1,), (0,)), ((), ())))
-    h = jnp.maximum(h, 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w2[0], (((1,), (0,)), ((), ()))) + b2[0], 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w3[0], (((1,), (0,)), ((), ()))) + b3[0], 0.0)
-    h = jnp.maximum(jax.lax.dot_general(
-        h, w4[0], (((1,), (0,)), ((), ()))) + b4[0], 0.0)
-    q = jax.lax.dot_general(h, w5[0], (((1,), (0,)), ((), ()))) + b5[0]
-    out_ref[0] = q[:, 0]
+    out_ref[0] = _qnet_body(
+        bits_ref[0], frac_ref[0], w1r[0], w1f[0], b1[0],
+        w2[0], b2[0], w3[0], b3[0], w4[0], b4[0], w5t[0], b5[0])
 
 
 def packed_qnet_stacked_rows(
@@ -127,8 +151,9 @@ def packed_qnet_stacked_rows(
     frac: jnp.ndarray,         # f32 [W, C, 1] steps-left feature column
     w1r: jnp.ndarray,          # f32 [W, 8, FP_BITS/8, H1] bit-plane W1 slices
     w1f: jnp.ndarray,          # f32 [W, 1, H1] the steps-left rows of W1
-    b1: jnp.ndarray,           # f32 [W, H1]
-    tail: list[tuple[jnp.ndarray, jnp.ndarray]],  # [(w, b)] layers 2..5, [W, ...]
+    b1: jnp.ndarray,           # f32 [W, 1, H1]
+    tail: list[tuple[jnp.ndarray, jnp.ndarray]],  # [(w, b [W, 1, out])]
+                               # layers 2..5, the last w as [W, 1, H4]
     *,
     row_block: int = ROW_BLOCK,
     interpret: bool = False,
@@ -136,7 +161,8 @@ def packed_qnet_stacked_rows(
     """The fleet-acting shape: grid (W, row blocks).  Each cell evaluates
     one worker's candidate-row block under that worker's own parameter
     slices (per-worker parameter selection moves into the BlockSpec index
-    maps — the kernel body is the per-worker row kernel unchanged)."""
+    maps — the kernel body is the per-worker row kernel unchanged).
+    -> q f32 ``[W, 1, C]``."""
     n_workers, N, n_bytes = bits.shape
     assert len(tail) == 4, "packed kernel is specialised to the MolDQN 5-layer MLP"
     row_block = min(row_block, N)
@@ -159,7 +185,9 @@ def packed_qnet_stacked_rows(
         _packed_qnet_stacked_kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, row_block), lambda wi, i: (wi, i)),
-        out_shape=jax.ShapeDtypeStruct((n_workers, N), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, row_block), lambda wi, i: (wi, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((n_workers, 1, N), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="packed_qnet_stacked_rows",
     )(bits, frac, *flat_w)

@@ -80,8 +80,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     mesh_desc = "x".join(str(s) for s in mesh.devices.shape)
     chips = mesh.devices.size
 
-    import contextlib
-    ambient = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else contextlib.nullcontext()
+    ambient = jax.set_mesh(mesh)
 
     params = M.abstract_params(cfg)
     # FSDP for the big archs: params+opt at TP-only exceed the HBM budget

@@ -35,10 +35,13 @@ def make_host_mesh(nd: int | None = None) -> Mesh:
     cross-nd bit-equality is only meaningful when every scenario runs in an
     identically-configured client — fixed forced device pool, varying
     submesh — rather than one client per device count.
+
+    The mesh's axis is of JAX's default ``Auto`` type (``jax.make_mesh``
+    would make it ``Explicit``, under which the stacked parameters' vmap
+    refuses the unplaced per-call operands of the per-worker path).
     """
-    if nd is None:
-        return jax.make_mesh((jax.device_count(),), ("data",))
     devices = jax.devices()
+    nd = len(devices) if nd is None else nd
     if nd <= 0 or nd > len(devices):
         raise ValueError(f"nd={nd} outside [1, {len(devices)}] visible devices")
     return Mesh(np.asarray(devices[:nd]), ("data",))

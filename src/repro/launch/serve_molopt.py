@@ -25,6 +25,7 @@ import time
 import jax
 
 from repro.core.agent import QNetwork
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.faults import FaultPlan, FaultRule
 from repro.predictors.service import OracleService, ResilientService, RetryPolicy
 from repro.serving import (MoleculeOptService, ServeConfig, StreamConfig,
@@ -45,8 +46,8 @@ def build_service(args) -> MoleculeOptService:
                       fail_attempts=1),
         ], seed=args.fault_seed)
     if args.trained:
-        from benchmarks.common import services
-        inner, *_ = services()
+        from repro.predictors.training import trained_service
+        inner, _ = trained_service(verbose=True)
     else:
         inner = OracleService()
     prop = ResilientService(inner, RetryPolicy(max_retries=1, seed=args.seed),
@@ -85,6 +86,7 @@ def main() -> None:
                     help="print machine-readable results instead of a table")
     args = ap.parse_args()
 
+    enable_compile_cache()
     svc = build_service(args)
     arrivals = seeded_request_stream(StreamConfig(
         n_requests=args.requests, rate=args.rate, seed=args.seed,

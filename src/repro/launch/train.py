@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -75,6 +76,7 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.mode == "rl":
         train_rl(args)
     else:
@@ -87,13 +89,10 @@ def train_rl(args) -> None:
         optimization_failure_rate
     from repro.data.datasets import antioxidant_dataset, dataset_property_table, \
         train_test_split
-    from repro.predictors import PropertyService
-    from repro.predictors.training import ensure_trained
-
     from repro.data.datasets import load_dataset
+    from repro.predictors.training import trained_service
 
-    bm, bp, im, ip_, metrics = ensure_trained()
-    service = PropertyService(bm, bp, im, ip_)
+    service, _ = trained_service(verbose=True)
     n_mols = args.workers * args.mols_per_worker
     if args.dataset is not None:
         # multi-start: reward normalisation and evaluation come from the

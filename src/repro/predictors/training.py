@@ -7,7 +7,9 @@ antioxidant sets with random edit-walks (the same action space the agent
 uses).  Accuracy target is the paper's: <5% average relative error (§2.2).
 
 ``ensure_trained`` is the entry point everything else uses: it trains once
-and caches params + a metrics json under ``.cache/predictors``.
+and caches params + a metrics json under ``.cache/predictors`` (or the
+``cache_dir`` it is given); ``trained_service`` wraps its result in the
+``PropertyService`` that training, serving and the benchmarks share.
 """
 
 from __future__ import annotations
@@ -28,7 +30,8 @@ from repro.optim import adam
 from repro.optim.adam import apply_updates
 from repro.predictors.gnn import AlfabetS, BDE_MEAN, BDE_SCALE
 from repro.predictors.ip_net import AIMNetS, IP_MEAN, IP_SCALE
-from repro.predictors.service import MAX_ATOMS, featurize, stack_features
+from repro.predictors.service import (
+    MAX_ATOMS, PropertyService, featurize, stack_features)
 
 DEFAULT_CACHE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", ".cache", "predictors")
 
@@ -210,7 +213,7 @@ def ensure_trained(cache_dir: str | None = None, *, steps: int = 1500, verbose: 
     mols = build_corpus()
     bde_model, bde_params, bde_metrics = train_bde_model(mols, steps=steps)
     ip_model, ip_params, ip_metrics = train_ip_model(mols, steps=steps)
-    metrics = {"bde": bde_metrics, "ip": ip_metrics}
+    metrics = {"bde": bde_metrics, "ip": ip_metrics, "steps": steps}
     if verbose:
         print(f"[predictors] BDE rel err {bde_metrics['rel_err_mean']:.3%}, "
               f"IP rel err {ip_metrics['rel_err_mean']:.3%}")
@@ -219,3 +222,12 @@ def ensure_trained(cache_dir: str | None = None, *, steps: int = 1500, verbose: 
     with open(meta_path, "w") as f:
         json.dump(metrics, f, indent=2)
     return bde_model, bde_params, ip_model, ip_params, metrics
+
+
+def trained_service(cache_dir: str | None = None, *, steps: int = 1500,
+                    verbose: bool = False) -> tuple[PropertyService, dict]:
+    """The trained BDE+IP ``PropertyService`` (train-or-load through
+    ``ensure_trained``) and the predictors' held-out metrics."""
+    bm, bp, im, ip_, metrics = ensure_trained(cache_dir, steps=steps,
+                                              verbose=verbose)
+    return PropertyService(bm, bp, im, ip_), metrics

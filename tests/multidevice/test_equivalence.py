@@ -1,8 +1,9 @@
 """The multi-device truth run: the (rollout x learner x chem x sync)
-equivalence matrix re-run at nd in {2, 4} forced host devices and pinned —
-transitions, loss trajectories and parameters bit-identical to the nd = 1
-reference of the same seed — with the recompiles-after-warmup gate held at
-0, plus the ragged fleets (W not divisible by nd) that pad to the mesh with
+equivalence matrix re-run at nd in {2, 4} forced host devices and pinned to
+the nd = 1 reference of the same seed under the cross-nd contract
+(``repro.launch.verify.assert_nd_equivalent``: transitions and rewards
+bit-identical, losses and parameters within a few float32 ulps) — with the
+recompiles-after-warmup gate held at 0, plus the ragged fleets (W not divisible by nd) that pad to the mesh with
 dead worker slots.
 
 Each cell spawns one ``repro.launch.verify`` subprocess per mesh size (the

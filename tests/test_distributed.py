@@ -108,6 +108,37 @@ def test_trainer_uses_host_mesh_and_pads_to_it():
     assert tr.n_padded_workers % tr.mesh.devices.size == 0
 
 
+def test_host_mesh_axes_are_auto():
+    """``jax.make_mesh`` builds Explicit axes, under which the default
+    path's vmaps refuse operands that are not placed like the stacked
+    params; the host mesh must be Auto at every nd."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    assert make_host_mesh().axis_types == (AxisType.Auto,)
+    assert make_host_mesh(1).axis_types == (AxisType.Auto,)
+
+
+def test_trainer_state_starts_sharded_from_worker_zero():
+    """Params, target and Adam state are born on the fleet sharding (never
+    whole on one device), every worker holding worker 0's initial weights
+    and zero moments."""
+    from repro.launch.mesh import fleet_sharding
+    tr = _trainer("episode")
+    want = fleet_sharding(tr.mesh)
+    for tree in (tr.params, tr.target_params, tr.opt_state):
+        for x in jax.tree_util.tree_leaves(tree):
+            assert x.sharding.is_equivalent_to(want, x.ndim)
+    keys = jax.random.split(jax.random.PRNGKey(tr.cfg.seed), tr.cfg.n_workers)
+    ref = tr.network.init(keys[0])
+    for x, r in zip(jax.tree_util.tree_leaves(tr.params),
+                    jax.tree_util.tree_leaves(ref)):
+        for w in range(tr.n_padded_workers):
+            np.testing.assert_array_equal(np.asarray(x[w]), np.asarray(r))
+    for x in jax.tree_util.tree_leaves((tr.opt_state.mu, tr.opt_state.nu,
+                                        tr.opt_state.step)):
+        assert not np.asarray(x).any()
+
+
 def test_loss_scalar_ignores_dead_padding_rows():
     tr = _trainer("episode")
     tr.n_live_workers = 2                      # live prefix of a padded vector

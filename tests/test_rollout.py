@@ -263,7 +263,7 @@ def test_packed_view_dead_rows_stay_zero():
 def test_fleet_one_q_dispatch_and_one_property_batch_per_step(pipelined):
     tr = _trainer("episode", "fleet_pipelined" if pipelined else "fleet")
     tr.engine.reset()
-    policy = tr._fleet_policy_sharded if pipelined else tr._fleet_policy
+    policy = tr._fleet_policy
     step = tr.engine.step_pipelined if pipelined else tr.engine.step
     steps = 0
     while not tr.engine.done:
