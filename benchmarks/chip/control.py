@@ -1,0 +1,118 @@
+#!/usr/bin/env python3
+"""Readings that set the limits of ``correct``: the program's own over
+many seeds, the control's, and planted faults'.  The benchmark's runs do
+not run this.
+
+    python3 benchmarks/chip/control.py --workload <cell> --seeds 1 2 3 \\
+        [--out chiprun_out/control]
+
+One process (the chip belongs to it) and, per seed, one program built and
+driven as a run drives it, through the warm episode.  Then, against the
+float32 reference of that seed:
+
+* ``program``  the numbers ``correct`` compares (the lower readings);
+* ``control``  the same numbers with the reference computed one precision
+  step lower (float8 e4m3 matmul operands) in the program's place;
+* faults planted in the reference put in the program's place:
+  ``half_batch`` (each update's mean over half its rows), ``no_sync`` (the
+  episode sync left out), ``answers_swapped`` (each Q answer, and each
+  prediction, handed to the neighbouring row).  A state left unchanged reads
+  1 on ``change_gap`` by definition and needs no run.
+
+Each seed's readings are one JSON line in ``<out>/<cell>.jsonl`` and on
+standard output.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+from pathlib import Path
+
+if __package__ in (None, ""):
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    __package__ = "chip"
+
+import numpy as np  # noqa: E402
+
+from . import correct as C, harness  # noqa: E402
+from .harness import log  # noqa: E402
+
+
+def train_readings(cfg: dict, pseed: int, capture: dict, rng) -> dict:
+    prog, aux = C.check_train(cfg, pseed, capture, rng)
+    learn_ref, q_ref, pred_ref = aux["learn_ref"], aux["q_ref"], aux["pred_ref"]
+    mols = aux["mols"]
+    q_ctl = C.ref_acting(cfg, pseed, capture, "fp8")
+    learn_ctl = C.ref_learner(cfg, pseed, capture["batches"], "fp8")
+    pred_ctl = C.ref_predictions(cfg, pseed, mols, "fp8")
+    control = {"q_gap": C.q_gap(q_ctl, q_ref),
+               **C.learner_numbers(learn_ctl, learn_ref, cfg),
+               "bde_gap": C.rel_gap(pred_ctl["bde"], pred_ref["bde"]),
+               "ip_gap": C.rel_gap(pred_ctl["ip"], pred_ref["ip"])}
+    B = capture["batches"][0]["state_bits"].shape[1]
+    half = C.learner_numbers(
+        C.ref_learner(cfg, pseed, capture["batches"], "highest", rows=B // 2),
+        learn_ref, cfg)
+    nosync = C.learner_numbers(
+        C.ref_learner(cfg, pseed, capture["batches"], "highest", sync=False),
+        learn_ref, cfg)
+    pp = aux["pred_prog"]
+    swapped = {"q_gap": C.q_gap([[np.roll(q, 1) for q in d["q"]]
+                                 for d in capture["dispatches"]], q_ref),
+               "bde_gap": C.rel_gap(np.roll(pp["bde"], 1), pred_ref["bde"]),
+               "ip_gap": C.rel_gap(np.roll(pp["ip"], 1), pred_ref["ip"])}
+    return {"program": prog, "control": control, "half_batch": half,
+            "no_sync": nosync, "answers_swapped": swapped,
+            "counts": aux["counts"]}
+
+
+def one_seed(cell: dict, seed: int) -> dict:
+    from .run import driver_class
+
+    wl = harness.workload(cell["name"])
+    cfg = harness.config(cell["config"])
+    spans = harness.Spans()
+    run = driver_class(wl["driver"])(cfg, wl, seed, spans)
+    t0 = time.perf_counter()
+    run.build()
+    run.warm()
+    t_prog = time.perf_counter() - t0
+    capture, pseed = run.capture, run.pseed
+    run.free()
+    rng = np.random.default_rng([int(seed), 0xC4EC])
+    out = train_readings(cfg, pseed, capture, rng)
+    out.update(seed=seed, program_s=t_prog,
+               reference_s=time.perf_counter() - t0 - t_prog)
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--out", default="chiprun_out/control")
+    args = ap.parse_args(argv)
+    harness.add_program_to_path()
+    harness.enable_compile_cache()
+    spec = harness.benchmark_spec()
+    cell = {c["name"]: c for c in spec["workloads"]}[args.workload]
+    harness.require_chip(cell["chips"])
+    out = Path(args.out)
+    out.mkdir(parents=True, exist_ok=True)
+    with open(out / f"{args.workload}.jsonl", "a") as f:
+        for seed in args.seeds:
+            r = one_seed(cell, seed)
+            line = json.dumps(r)
+            f.write(line + "\n")
+            f.flush()
+            print(line, flush=True)
+            log(f"[control] seed {seed}: program {r['program']} control "
+                f"{r['control']}")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,268 @@
+"""How ``correct`` is decided: what the timed path produced, against the
+plain float32 references in ``reference/``.
+
+The program's *readings* and the reference's are the same structure;
+``*_numbers`` turns a pair into the compared numbers, each with its limit
+in the configuration file.  The control (``control.py``) puts the
+reference, computed one precision step lower (``mode="fp8"``), in the
+program's place, and planted faults put a broken reference there.
+
+Training (the warm episode, which the window's own call ran):
+    q_gap          acting: worst |Q - Q_ref| over every valid candidate row
+                   of every acting dispatch (initial weights), over the
+                   median |Q_ref|
+    fp_rows_wrong  candidate rows, of a seed-drawn sample, whose packed
+                   fingerprint differs from the reference's (exact)
+    loss_gap       the first learner call: worst relative gap of the
+                   per-update mean loss
+    grad_gap       the first clipped gradient, read from the optimizer's
+                   state after one update: worst leaf (per worker)
+    change_gap     the parameters' change after the learner call and the
+                   episode sync: worst leaf (per worker)
+    bde_gap, ip_gap   worst relative gap of the predictors' answers on a
+                   seed-drawn sample of the molecules they were asked about
+
+A gap of norms is measured against the larger of the reference's norm of
+that leaf and of the median kept leaf.  Leaves whose reference gradient
+is under ``leaf_grad_floor`` times the median leaf's are left out of both
+norm gaps (Adam moves them by round-off alone).
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from .reference import features as rfeat, morgan, predictors as rpred, qnet as rq
+
+FP_SAMPLE = 2048
+PRED_SAMPLE = 512
+
+
+def _median_abs(x: np.ndarray) -> float:
+    m = float(np.median(np.abs(x))) if x.size else 0.0
+    return m if m > 0 else 1.0
+
+
+def qnet_sizes(cfg: dict) -> tuple[int, ...]:
+    q = cfg["qnet"]
+    return (q["in_dim"],) + tuple(q["hidden"]) + (1,)
+
+
+def learner_hp(cfg: dict) -> dict:
+    lr = cfg["learner"]
+    return {k: lr[k] for k in ("lr", "b1", "b2", "eps", "clip", "discount")}
+
+
+def weight_keys(pseed: int):
+    """(Q, BDE, IP) keys: the same derivation the harness gives the
+    program (``drivers.common.weight_keys``), written out here."""
+    import jax
+
+    base = jax.random.PRNGKey(pseed)
+    return tuple(jax.random.fold_in(base, i) for i in (0, 1, 2))
+
+
+# ------------------------------------------------------------------ #
+# samples of what the program produced
+# ------------------------------------------------------------------ #
+def sample(items: list, limit: int, rng: np.random.Generator) -> list:
+    if len(items) <= limit:
+        return list(items)
+    return [items[i] for i in np.sort(rng.choice(len(items), limit, replace=False))]
+
+
+def train_fp_rows(capture: dict) -> list:
+    """(program packed row, candidate action) of every acting row."""
+    rows = []
+    for d in capture["dispatches"]:
+        for bits, cands in zip(d["bits"], d["cands"]):
+            rows += [(bits[r], cands[r]) for r in range(len(cands))]
+    return rows
+
+
+def predicted_pairs(record: list) -> list:
+    """Distinct (molecule, properties) pairs of the recorded predict
+    calls.  The service answers a molecule from its cache by isomorphism
+    key, and the IP network's conformer features depend on the atom
+    labelling, so each pair takes the molecule as the service first saw it
+    (the labelling it computed): the answer cache is empty when recording
+    starts."""
+    seen, pairs = set(), []
+    for mols, props in record:
+        for m, p in zip(mols, props):
+            k = m.iso_key()
+            if p is not None and k not in seen:
+                seen.add(k)
+                pairs.append((m, p))
+    return pairs
+
+
+# ------------------------------------------------------------------ #
+# the reference
+# ------------------------------------------------------------------ #
+def ref_fingerprints(actions: list) -> np.ndarray:
+    out = [morgan.packed_fingerprint(a.result.elements, a.result.bonds)
+           for a in actions]
+    return np.stack(out) if out else np.zeros((0, 256), np.uint8)
+
+
+def ref_predictions(cfg: dict, pseed: int, mols: list, mode: str) -> dict:
+    """Reference BDE and IP of each molecule (its elements and bonds as
+    the program asked about it), on the reference's own features."""
+    import jax.numpy as jnp
+
+    p = cfg["predictors"]
+    if not mols:
+        return {"bde": np.zeros(0), "ip": np.zeros(0)}
+    f = rfeat.features([(m.elements, m.bonds) for m in mols], p["max_atoms"])
+    keys = weight_keys(pseed)
+    bde_w = rpred.init_bde(keys[1], p["atom_feat"], p["bde_hidden"], p["bde_rounds"])
+    ip_w = rpred.init_ip(keys[2], p["atom_feat"] + p["conf_feat"], p["ip_hidden"],
+                         p["ip_ensemble"])
+    a, mask = jnp.asarray(f["atom_feat"]), jnp.asarray(f["mask"])
+    bde = np.asarray(rpred.bde(bde_w, a, jnp.asarray(f["adj"]), mask, mode=mode))
+    ip = np.asarray(rpred.ip(ip_w, a, jnp.asarray(f["conf_feat"]), mask, mode=mode))
+    return {"bde": np.where(f["has_oh"], bde, np.nan),
+            "ip": np.where(f["conf_valid"] > 0.5, ip, np.nan)}
+
+
+def program_predictions(pairs: list) -> dict:
+    nan = float("nan")
+    return {"bde": np.array([nan if p.bde is None else p.bde for _, p in pairs]),
+            "ip": np.array([nan if p.ip is None else p.ip for _, p in pairs])}
+
+
+def initial_qnet(cfg: dict, pseed: int):
+    """The trainer's initial weights, every worker's: worker 0's key of
+    the seed's split over the fleet."""
+    import jax
+
+    W = cfg["trainer"]["n_workers"]
+    return rq.init_qnet(jax.random.split(jax.random.PRNGKey(pseed), W)[0],
+                        qnet_sizes(cfg))
+
+
+def ref_acting(cfg: dict, pseed: int, capture: dict, mode: str) -> list:
+    """Reference Q of every acting row of the warm episode: every worker
+    still holds the initial weights there."""
+    p0 = initial_qnet(cfg, pseed)
+    out = []
+    for d in capture["dispatches"]:
+        sizes = [b.shape[0] for b in d["bits"]]
+        q = rq.q_rows_blocked(p0, np.concatenate(d["bits"]),
+                              np.concatenate(d["frac"]).astype(np.float32), mode)
+        out.append(np.split(q, np.cumsum(sizes)[:-1]))
+    return out
+
+
+def ref_learner(cfg: dict, pseed: int, batches: list, mode: str,
+                rows: int | None = None, sync: bool = True) -> dict:
+    return rq.learner_reference(initial_qnet(cfg, pseed), batches,
+                                hp=learner_hp(cfg), mode=mode, rows=rows,
+                                sync=sync)
+
+
+# ------------------------------------------------------------------ #
+# the compared numbers
+# ------------------------------------------------------------------ #
+def q_gap(prog_q: list, ref_q: list) -> float:
+    diffs, refs = [], []
+    for pd, rd in zip(prog_q, ref_q):
+        for p, r in zip(pd, rd):
+            if r.size:
+                diffs.append(np.abs(np.asarray(p, np.float32)[:r.size] - r))
+                refs.append(r)
+    if not diffs:
+        return float("inf")
+    return float(np.max(np.concatenate(diffs)) / _median_abs(np.concatenate(refs)))
+
+
+def fp_rows_wrong(prog_rows: np.ndarray, ref_rows: np.ndarray) -> int:
+    return int(np.count_nonzero(np.any(prog_rows != ref_rows, axis=-1)))
+
+
+def rel_gap(prog: np.ndarray, ref: np.ndarray) -> float:
+    """Worst gap where the reference has an answer, over the larger of
+    that answer's size and the median answer's (an untrained predictor's
+    answer can lie near 0); inf where the program has none there."""
+    fr = np.isfinite(ref)
+    if not fr.any():
+        return 0.0
+    if np.any(fr & ~np.isfinite(prog)):
+        return float("inf")
+    den = np.maximum(np.abs(ref[fr]), _median_abs(ref[fr]))
+    return float(np.max(np.abs(prog[fr] - ref[fr]) / den))
+
+
+def kept_leaves(ref_grad_norms: np.ndarray, cfg: dict) -> np.ndarray:
+    med = float(np.median(ref_grad_norms))
+    return ref_grad_norms >= cfg["limits"]["leaf_grad_floor"] * med
+
+
+def _norm_gaps(prog: np.ndarray, ref: np.ndarray, keep: np.ndarray) -> np.ndarray:
+    med = float(np.median(ref[keep])) if keep.any() else 1.0
+    return np.where(keep, np.abs(prog - ref) / np.maximum(ref, med), 0.0)
+
+
+def norm_gap(prog: np.ndarray, ref: np.ndarray, keep: np.ndarray) -> float:
+    return float(np.max(_norm_gaps(prog, ref, keep)))
+
+
+def worst_leaf(prog: np.ndarray, ref: np.ndarray, keep: np.ndarray) -> str:
+    """Where a norm gap peaks: ``w<worker>/leaf<index>`` (leaves in
+    ``tree_leaves`` order: each layer's bias, then its weight)."""
+    w, leaf = np.unravel_index(np.argmax(_norm_gaps(prog, ref, keep)), ref.shape)
+    return f"w{w}/leaf{leaf}"
+
+
+def learner_numbers(prog: dict, ref: dict, cfg: dict) -> dict:
+    keep = kept_leaves(ref["grad_norms"], cfg)
+    n = min(len(prog["losses"]), len(ref["losses"]))
+    return {
+        "loss_gap": float(np.max(np.abs(prog["losses"][:n] - ref["losses"][:n])
+                                 / np.abs(ref["losses"][:n]))),
+        "grad_gap": norm_gap(prog["grad_norms"], ref["grad_norms"], keep),
+        "change_gap": norm_gap(prog["change_norms"], ref["change_norms"], keep),
+    }
+
+
+def judge(numbers: dict, limits: dict) -> dict:
+    return {k: {"value": float(v), "limit": limits[k], "ok": bool(v <= limits[k])}
+            for k, v in numbers.items()}
+
+
+# ------------------------------------------------------------------ #
+# one cell's check, program against reference
+# ------------------------------------------------------------------ #
+def check_train(cfg: dict, pseed: int, capture: dict,
+                rng: np.random.Generator) -> tuple[dict, dict]:
+    """Numbers of the program against the reference, and what the
+    control needs to reuse (samples and the reference's readings)."""
+    rows = sample(train_fp_rows(capture), FP_SAMPLE, rng)
+    pairs = sample(predicted_pairs(capture["predictions"]), PRED_SAMPLE, rng)
+    fp_ref = ref_fingerprints([a for _, a in rows])
+    fp_prog = np.stack([r for r, _ in rows])
+    q_ref = ref_acting(cfg, pseed, capture, "highest")
+    learn_ref = ref_learner(cfg, pseed, capture["batches"], "highest")
+    pred_ref = ref_predictions(cfg, pseed, [m for m, _ in pairs], "highest")
+    pred_prog = program_predictions(pairs)
+    prog_learn = {k: capture[k] for k in ("losses", "grad_norms", "change_norms")}
+    numbers = {"q_gap": q_gap([d["q"] for d in capture["dispatches"]], q_ref),
+               "fp_rows_wrong": fp_rows_wrong(fp_prog, fp_ref),
+               **learner_numbers(prog_learn, learn_ref, cfg),
+               "bde_gap": rel_gap(pred_prog["bde"], pred_ref["bde"]),
+               "ip_gap": rel_gap(pred_prog["ip"], pred_ref["ip"])}
+    return numbers, {"q_ref": q_ref, "learn_ref": learn_ref, "pred_ref": pred_ref,
+                     "mols": [m for m, _ in pairs], "pred_prog": pred_prog,
+                     "counts": {"acting rows": sum(r.size for d in q_ref for r in d),
+                                "leaves left out": int(
+                                    (~kept_leaves(learn_ref["grad_norms"], cfg)).sum()),
+                                "grad gap at": worst_leaf(
+                                    prog_learn["grad_norms"], learn_ref["grad_norms"],
+                                    kept_leaves(learn_ref["grad_norms"], cfg)),
+                                "change gap at": worst_leaf(
+                                    prog_learn["change_norms"], learn_ref["change_norms"],
+                                    kept_leaves(learn_ref["grad_norms"], cfg)),
+                                "fingerprint rows": len(rows),
+                                "learner updates": len(learn_ref["losses"]),
+                                "molecules": len(pairs)}}
