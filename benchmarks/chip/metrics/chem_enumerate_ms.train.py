@@ -1,0 +1,15 @@
+"""Host milliseconds per environment step in candidate enumeration: the
+program's ``chem.enumerate`` spans (chemistry-cache lookups, in-batch
+dedup, enumerating the misses) inside the traced window, over the
+window's env steps."""
+
+from chip import program_spans
+
+
+def read(ctx):
+    d = ctx["delta"]
+    steps = d["chem"]["env_steps"]
+    tot = program_spans.window_totals(ctx)
+    if ctx["driver"] != "train" or not steps or not tot or "chem.enumerate" not in tot:
+        return None
+    return 1e3 * tot["chem.enumerate"]["s"] / steps

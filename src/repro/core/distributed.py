@@ -108,6 +108,7 @@ from repro.launch.mesh import fleet_sharding, make_host_mesh, padded_worker_coun
 from repro.optim import adam
 from repro.optim.adam import apply_updates
 from repro.predictors.service import PropertyService
+from repro.spans import span
 
 ROLLOUT_MODES = ("fleet", "fleet_sharded", "fleet_pipelined", "per_worker")
 _FLEET_MODES = ("fleet", "fleet_sharded", "fleet_pipelined")
@@ -718,20 +719,22 @@ class DistributedTrainer:
         """One paper episode: rollouts on all workers, local training
         updates, then (episode mode) the parameter sync."""
         cfg = self.cfg
-        records = self.rollout_episode()
+        with span("train.episode"):
+            records = self.rollout_episode()
 
-        losses = []
-        min_fill = min(len(b) for b in self.buffers)
-        if min_fill >= cfg.train_batch_size:
-            losses = self.run_updates(cfg.updates_per_episode)
+            losses = []
+            min_fill = min(len(b) for b in self.buffers)
+            if min_fill >= cfg.train_batch_size:
+                losses = self.run_updates(cfg.updates_per_episode)
 
-        if cfg.sync_mode == "episode":
-            self.params = self._sync(self.params)
-            self.opt_state = self._sync_opt(self.opt_state)
+            if cfg.sync_mode == "episode":
+                with span("train.sync"):
+                    self.params = self._sync(self.params)
+                    self.opt_state = self._sync_opt(self.opt_state)
 
-        self.episode += 1
-        if self.episode % cfg.dqn.target_update_episodes == 0:
-            self.target_params = jax.tree_util.tree_map(jnp.copy, self.params)
+            self.episode += 1
+            if self.episode % cfg.dqn.target_update_episodes == 0:
+                self.target_params = jax.tree_util.tree_map(jnp.copy, self.params)
         self.epsilon = max(self.epsilon * cfg.dqn.epsilon_decay, cfg.dqn.epsilon_min)
 
         flat = [r for recs in records for r in recs]
@@ -885,9 +888,10 @@ class DistributedTrainer:
         """Seed path host work: one DENSE float32 sample per worker buffer,
         stacked to ``[W_pad, B, ...]`` (what `_stacked_sample` ships)."""
         kw = self._sample_kwargs()
-        return self._pad_stacked(
-            [b.sample(self.cfg.train_batch_size, self.cfg.max_candidates, **kw)
-             for b in self.buffers])
+        with span("learner.sample"):
+            return self._pad_stacked(
+                [b.sample(self.cfg.train_batch_size, self.cfg.max_candidates, **kw)
+                 for b in self.buffers])
 
     def _stacked_sample_packed_np(self) -> dict[str, np.ndarray]:
         """Packed path host work: uint8 bit planes + scalars, stacked to
@@ -896,10 +900,11 @@ class DistributedTrainer:
         indices as the dense sampler, which is what makes the two learner
         paths loss-trajectory-identical (tests/test_learner.py)."""
         kw = self._sample_kwargs()
-        return self._pad_stacked(
-            [b.sample_packed(self.cfg.train_batch_size, self.cfg.max_candidates,
-                             **kw)
-             for b in self.buffers])
+        with span("learner.sample"):
+            return self._pad_stacked(
+                [b.sample_packed(self.cfg.train_batch_size, self.cfg.max_candidates,
+                                 **kw)
+                 for b in self.buffers])
 
     def _ship(self, host_batch: dict[str, np.ndarray]) -> dict[str, jnp.ndarray]:
         self.h2d_update_bytes += packed_nbytes(host_batch)
@@ -937,8 +942,9 @@ class DistributedTrainer:
         """Scalar loss over the LIVE workers of a ``[W_pad]`` loss vector
         (dead mesh-padding rows carry zero-batch garbage).  Computed the
         same way at every mesh size so loss trajectories are comparable
-        bit for bit across nd."""
-        return float(np.asarray(loss)[: self.n_live_workers].mean())
+        bit for bit across nd.  The host blocks on the device here."""
+        with span("learner.wait"):
+            return float(np.asarray(loss)[: self.n_live_workers].mean())
 
     def _get_sampler(self) -> ThreadPoolExecutor:
         if self._sampler_pool is None:
@@ -963,31 +969,32 @@ class DistributedTrainer:
         if n <= 0:
             return []   # before the eager submit below: a zero-update call
             # must not advance the buffers' sample RNG streams
-        mode = self.cfg.learner
-        prioritized = self.cfg.replay == "prioritized"
-        if mode != "packed_pipelined" or prioritized:
-            packed = mode != "dense"
-            losses = []
-            for _ in range(n):
-                batch = self._stacked_sample_packed() if packed \
-                    else self._stacked_sample()
-                loss, td = self._update_once(batch, packed=packed)
-                if prioritized:
-                    self._apply_priorities(td)
-                losses.append(self._loss_scalar(loss))
-            return losses
-        pool = self._get_sampler()
-        fut = pool.submit(self._stacked_sample_packed_np)
-        device_losses = []
-        for k in range(n):
-            host_batch = fut.result()
-            if k + 1 < n:
-                fut = pool.submit(self._stacked_sample_packed_np)
-            # the update dispatch is async: XLA computes while the sampler
-            # thread gathers; only the final host conversions block
-            device_losses.append(
-                self._update_once(self._ship(host_batch), packed=True)[0])
-        return [self._loss_scalar(l) for l in device_losses]
+        with span("learner.updates"):
+            mode = self.cfg.learner
+            prioritized = self.cfg.replay == "prioritized"
+            if mode != "packed_pipelined" or prioritized:
+                packed = mode != "dense"
+                losses = []
+                for _ in range(n):
+                    batch = self._stacked_sample_packed() if packed \
+                        else self._stacked_sample()
+                    loss, td = self._update_once(batch, packed=packed)
+                    if prioritized:
+                        self._apply_priorities(td)
+                    losses.append(self._loss_scalar(loss))
+                return losses
+            pool = self._get_sampler()
+            fut = pool.submit(self._stacked_sample_packed_np)
+            device_losses = []
+            for k in range(n):
+                host_batch = fut.result()
+                if k + 1 < n:
+                    fut = pool.submit(self._stacked_sample_packed_np)
+                # the update dispatch is async: XLA computes while the sampler
+                # thread gathers; only the final host conversions block
+                device_losses.append(
+                    self._update_once(self._ship(host_batch), packed=True)[0])
+            return [self._loss_scalar(l) for l in device_losses]
 
     def train(self, episodes: int | None = None, log_every: int = 0) -> list[dict]:
         stats = []

@@ -51,7 +51,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Protocol, Sequence, runtime_checkable
@@ -68,6 +67,7 @@ from repro.core.faults import FaultError, Incident, TransientFault
 from repro.core.replay import FP_BYTES, ReplayBuffer, Transition, unpack_fp
 from repro.core.reward import (
     CompiledObjective, ObjectiveSpec, RewardConfig, evaluate_rewards)
+from repro.spans import span
 
 STATE_DIM = FP_BITS + 1  # fingerprint ++ steps-left feature
 
@@ -251,8 +251,10 @@ class RolloutEngine:
         self._compiled_objectives: dict[tuple[int, object], CompiledObjective] = {}
         self.workers: list[list[Slot]] = []
         self.n_env_steps = 0
-        self.chem_enum_s = 0.0   # host seconds in candidate enumeration
-        self.chem_fp_s = 0.0     # host seconds in candidate fingerprints
+        # host seconds in candidate enumeration / fingerprints: the sums of
+        # this engine's chem.enumerate / chem.fingerprint span readings
+        self.chem_enum_s = 0.0
+        self.chem_fp_s = 0.0
         self._stats_lock = threading.Lock()  # pipelined threads accumulate
         # self-healing: a slot whose chem/property path raises a terminal
         # FaultError drains to dead under quarantine (empty successor set,
@@ -419,22 +421,19 @@ class RolloutEngine:
         """
         if self.chem == "incremental":
             return self._compute_enum_incremental(mols)
-        t0 = time.perf_counter()
-        cands = [self._enum_or_failure(m) for m in mols]
-        t1 = time.perf_counter()
+        with span("chem.enumerate") as t_enum:
+            cands = [self._enum_or_failure(m) for m in mols]
         # the full path materialises every candidate and recomputes every
         # fingerprint from scratch — the pinned reference behaviour.
         # Failed molecules carry their sentinel through; their siblings'
         # fingerprint batch is unchanged (composition-independent).
-        flat = [a.result for acts in cands
-                if not isinstance(acts, _EnumFailure) for a in acts]
-        fps = batch_morgan_fingerprints(flat) if flat else \
-            np.zeros((0, FP_BITS), np.float32)
-        packed = pack_fps(fps)
-        t2 = time.perf_counter()
-        with self._stats_lock:
-            self.chem_enum_s += t1 - t0
-            self.chem_fp_s += t2 - t1
+        with span("chem.fingerprint") as t_fp:
+            flat = [a.result for acts in cands
+                    if not isinstance(acts, _EnumFailure) for a in acts]
+            fps = batch_morgan_fingerprints(flat) if flat else \
+                np.zeros((0, FP_BITS), np.float32)
+            packed = pack_fps(fps)
+        self._add_chem_seconds(t_enum.s, t_fp.s)
         out, off = [], 0
         for acts in cands:
             if isinstance(acts, _EnumFailure):
@@ -452,61 +451,63 @@ class RolloutEngine:
         derive all candidate fingerprints from ONE shared parent env-hash
         table per slot, batched across the miss slots."""
         cache = self.chem_cache
-        t0 = time.perf_counter()
-        out: list = [None] * len(mols)
-        miss: list[int] = []
-        for i, m in enumerate(mols):
-            entry = cache.get(m) if cache is not None else None
-            if entry is not None:
-                out[i] = (entry.actions, None, entry.packed_fps)
-            else:
-                miss.append(i)
-        # in-batch dedup (the PropertyService idiom): workers sharing a
-        # concrete parent — e.g. every slot at episode start — enumerate it
-        # ONCE per step and share the (immutable) results
-        uniq: list[int] = []
-        rep_of: dict[bytes, int] = {}
-        dup_of: dict[int, int] = {}
-        for i in miss:
-            sig = molecule_signature(mols[i])
-            if sig in rep_of:
-                dup_of[i] = rep_of[sig]
-            else:
-                rep_of[sig] = i
-                uniq.append(i)
-        acts_by = [self._enum_or_failure(mols[i]) for i in uniq]
-        t1 = time.perf_counter()
-        # failed molecules keep their sentinel; only intact ones enter the
-        # grouped fingerprint batch and the cache (all-or-nothing put)
-        good = [(i, acts) for i, acts in zip(uniq, acts_by)
-                if not isinstance(acts, _EnumFailure)]
-        for i, acts in zip(uniq, acts_by):
-            if isinstance(acts, _EnumFailure):
-                out[i] = acts
-        if good:
-            fps_by = incremental_fingerprints_grouped(
-                [mols[i] for i, _ in good], [acts for _, acts in good])
-            for (i, acts), fps in zip(good, fps_by):
-                packed = pack_fps(fps)
-                if cache is not None:
-                    cache.put(mols[i], acts, packed)
-                out[i] = (acts, fps, packed)
-        for i, rep in dup_of.items():
-            out[i] = out[rep]   # duplicates share results AND failures
-        # cache hits rebuild the dense rows from the packed bits (exact:
-        # the fingerprints are {0,1}-valued) — unless the engine runs
-        # packed acting, where nothing ever reads the dense rows and the
-        # unpack would be the hot path's only host f32 materialisation
-        if not self.packed_states:
-            out = [res if isinstance(res, _EnumFailure) else
-                   (res[0], unpack_fp(res[2]) if res[1] is None else res[1],
-                    res[2])
-                   for res in out]
-        t2 = time.perf_counter()
-        with self._stats_lock:
-            self.chem_enum_s += t1 - t0
-            self.chem_fp_s += t2 - t1
+        with span("chem.enumerate") as t_enum:
+            out: list = [None] * len(mols)
+            miss: list[int] = []
+            for i, m in enumerate(mols):
+                entry = cache.get(m) if cache is not None else None
+                if entry is not None:
+                    out[i] = (entry.actions, None, entry.packed_fps)
+                else:
+                    miss.append(i)
+            # in-batch dedup (the PropertyService idiom): workers sharing a
+            # concrete parent — e.g. every slot at episode start — enumerate
+            # it ONCE per step and share the (immutable) results
+            uniq: list[int] = []
+            rep_of: dict[bytes, int] = {}
+            dup_of: dict[int, int] = {}
+            for i in miss:
+                sig = molecule_signature(mols[i])
+                if sig in rep_of:
+                    dup_of[i] = rep_of[sig]
+                else:
+                    rep_of[sig] = i
+                    uniq.append(i)
+            acts_by = [self._enum_or_failure(mols[i]) for i in uniq]
+        with span("chem.fingerprint") as t_fp:
+            # failed molecules keep their sentinel; only intact ones enter
+            # the grouped fingerprint batch and the cache (all-or-nothing put)
+            good = [(i, acts) for i, acts in zip(uniq, acts_by)
+                    if not isinstance(acts, _EnumFailure)]
+            for i, acts in zip(uniq, acts_by):
+                if isinstance(acts, _EnumFailure):
+                    out[i] = acts
+            if good:
+                fps_by = incremental_fingerprints_grouped(
+                    [mols[i] for i, _ in good], [acts for _, acts in good])
+                for (i, acts), fps in zip(good, fps_by):
+                    packed = pack_fps(fps)
+                    if cache is not None:
+                        cache.put(mols[i], acts, packed)
+                    out[i] = (acts, fps, packed)
+            for i, rep in dup_of.items():
+                out[i] = out[rep]   # duplicates share results AND failures
+            # cache hits rebuild the dense rows from the packed bits (exact:
+            # the fingerprints are {0,1}-valued) — unless the engine runs
+            # packed acting, where nothing ever reads the dense rows and the
+            # unpack would be the hot path's only host f32 materialisation
+            if not self.packed_states:
+                out = [res if isinstance(res, _EnumFailure) else
+                       (res[0], unpack_fp(res[2]) if res[1] is None else res[1],
+                        res[2])
+                       for res in out]
+        self._add_chem_seconds(t_enum.s, t_fp.s)
         return out
+
+    def _add_chem_seconds(self, enum_s: float, fp_s: float) -> None:
+        with self._stats_lock:
+            self.chem_enum_s += enum_s
+            self.chem_fp_s += fp_s
 
     def _apply_enum(self, slots: Sequence[Slot],
                     results: Sequence[tuple[Sequence[Action], np.ndarray, np.ndarray]]
@@ -537,7 +538,8 @@ class RolloutEngine:
         slot of every worker (the reference, single-threaded pass)."""
         todo = [s for slots in self.workers for s in slots if s.steps_left > 0]
         if todo:
-            self._apply_enum(todo, self._compute_enum([s.current for s in todo]))
+            with span("rollout.enumerate"):
+                self._apply_enum(todo, self._compute_enum([s.current for s in todo]))
 
     # ------------------------------------------------------------ #
     # step helpers shared by the reference and pipelined paths
@@ -547,29 +549,35 @@ class RolloutEngine:
         """Move completed pending transitions into the per-worker buffers."""
         if buffers is None:
             return
-        for w, live in enumerate(live_by_worker):
-            buf = buffers[w]
-            if buf is None:
-                continue
-            ready = [s for s in live
-                     if s.pending is not None and s.pending.next_fps is not None]
-            buf.add_many(s.pending for s in ready)
-            for s in ready:
-                s.pending = None
+        with span("rollout.flush"):
+            for w, live in enumerate(live_by_worker):
+                buf = buffers[w]
+                if buf is None:
+                    continue
+                ready = [s for s in live
+                         if s.pending is not None and s.pending.next_fps is not None]
+                buf.add_many(s.pending for s in ready)
+                for s in ready:
+                    s.pending = None
 
     def _flush_dead(self, buffers: Sequence[ReplayBuffer | None] | None) -> None:
         """Flush completed pendings of slots that died mid-episode (no legal
-        candidates) — no later step will ever visit them again."""
+        candidates) — no later step will ever visit them again.  The
+        ``rollout.flush`` span opens only when one did (rare), so a step
+        opens it once, in ``_flush_ready``."""
         if buffers is None:
             return
-        for w, slots in enumerate(self.workers):
-            buf = buffers[w]
-            for s in slots:
-                if (s.steps_left <= 0 and s.pending is not None
-                        and s.pending.next_fps is not None):
-                    if buf is not None:
-                        buf.add(s.pending)
-                    s.pending = None
+        dead = [s for slots in self.workers for s in slots
+                if s.steps_left <= 0 and s.pending is not None
+                and s.pending.next_fps is not None]
+        if not dead:
+            return
+        with span("rollout.flush"):
+            for s in dead:
+                buf = buffers[s.worker]
+                if buf is not None:
+                    buf.add(s.pending)
+                s.pending = None
 
     def _build_states(self, live_by_worker: Sequence[Sequence[Slot]]
                       ) -> list[np.ndarray]:
@@ -615,21 +623,23 @@ class RolloutEngine:
         explored index, or -1 for argmax-when-Q-lands) in the reference
         worker-major slot order — the host-side half of action selection,
         run while the async Q dispatch is still in flight on device."""
-        return [[policy.plan_action(len(s.candidates), w) for s in live]
-                for w, live in enumerate(live_by_worker)]
+        with span("rollout.select"):
+            return [[policy.plan_action(len(s.candidates), w) for s in live]
+                    for w, live in enumerate(live_by_worker)]
 
     def _dispatch_q(self, live_by_worker: Sequence[Sequence[Slot]],
                     policy) -> tuple[Sequence[np.ndarray], list[list[int]] | None]:
         """One fleet Q dispatch in the policy's preferred representation
         (dense f32 reference, packed u8, or packed + pre-drawn plans)."""
-        if getattr(policy, "wants_packed_states", False):
-            bits_pw, frac_pw = self._build_states_packed(live_by_worker)
-            if getattr(policy, "async_q", False):
-                handle = policy.fleet_q_dispatch_packed(bits_pw, frac_pw)
-                plans = self._plan_selection(live_by_worker, policy)
-                return policy.fleet_q_fetch(handle), plans
-            return policy.fleet_q_values_packed(bits_pw, frac_pw), None
-        return policy.fleet_q_values(self._build_states(live_by_worker)), None
+        with span("rollout.q_dispatch"):
+            if getattr(policy, "wants_packed_states", False):
+                bits_pw, frac_pw = self._build_states_packed(live_by_worker)
+                if getattr(policy, "async_q", False):
+                    handle = policy.fleet_q_dispatch_packed(bits_pw, frac_pw)
+                    plans = self._plan_selection(live_by_worker, policy)
+                    return policy.fleet_q_fetch(handle), plans
+                return policy.fleet_q_values_packed(bits_pw, frac_pw), None
+            return policy.fleet_q_values(self._build_states(live_by_worker)), None
 
     def _select(self, live_by_worker: Sequence[Sequence[Slot]],
                 q_by_worker: Sequence[np.ndarray], policy: FleetPolicy,
@@ -643,22 +653,23 @@ class RolloutEngine:
         sync branch uses.  The chosen tuple carries the PACKED fingerprint
         row — it becomes the replay ``state_fp`` without a repack."""
         chosen: list[tuple[Slot, Action, np.ndarray]] = []
-        for w, live in enumerate(live_by_worker):
-            q_all, off = q_by_worker[w], 0
-            for i, s in enumerate(live):
-                ln = len(s.candidates)
-                if ln == 0:  # _apply_enum kills candidate-less slots
-                    raise RuntimeError(
-                        f"invariant violation: live slot (worker {w}, index "
-                        f"{s.index}) reached selection with zero candidates")
-                if plans is None:
-                    a_idx = policy.select_action(q_all[off:off + ln], w)
-                else:
-                    a_idx = plans[w][i]
-                    if a_idx < 0:
-                        a_idx = int(np.argmax(q_all[off:off + ln]))
-                off += ln
-                chosen.append((s, s.candidates[a_idx], s.cand_fps_packed[a_idx]))
+        with span("rollout.select"):
+            for w, live in enumerate(live_by_worker):
+                q_all, off = q_by_worker[w], 0
+                for i, s in enumerate(live):
+                    ln = len(s.candidates)
+                    if ln == 0:  # _apply_enum kills candidate-less slots
+                        raise RuntimeError(
+                            f"invariant violation: live slot (worker {w}, index "
+                            f"{s.index}) reached selection with zero candidates")
+                    if plans is None:
+                        a_idx = policy.select_action(q_all[off:off + ln], w)
+                    else:
+                        a_idx = plans[w][i]
+                        if a_idx < 0:
+                            a_idx = int(np.argmax(q_all[off:off + ln]))
+                    off += ln
+                    chosen.append((s, s.candidates[a_idx], s.cand_fps_packed[a_idx]))
         return chosen
 
     def _predict_chosen(self, service, chosen):
@@ -669,20 +680,21 @@ class RolloutEngine:
         poisoned successor quarantines one slot, not the fleet; failed rows
         come back as ``None``."""
         mols = [a.result for _, a, _ in chosen]
-        try:
-            return service.predict(mols)
-        except FaultError:
-            props = []
-            for (s, a, _), m in zip(chosen, mols, strict=True):
-                try:
-                    props.append(service.predict([m])[0])
-                except FaultError as e:
-                    props.append(None)
-                    self._record_incident(
-                        site="predict", worker=s.worker, slot=s.index,
-                        key=m.canonical_key(), error=repr(e),
-                        action="quarantined")
-            return props
+        with span("rollout.predict"):
+            try:
+                return service.predict(mols)
+            except FaultError:
+                props = []
+                for (s, a, _), m in zip(chosen, mols, strict=True):
+                    try:
+                        props.append(service.predict([m])[0])
+                    except FaultError as e:
+                        props.append(None)
+                        self._record_incident(
+                            site="predict", worker=s.worker, slot=s.index,
+                            key=m.canonical_key(), error=repr(e),
+                            action="quarantined")
+                return props
 
     def _resolve_objective(self, obj, worker: int):
         """Normalise a slot/fleet objective to what the reward layer
@@ -792,47 +804,48 @@ class RolloutEngine:
         fleet reward layer) quarantines identically, with its
         ``site="reward"`` Incident already on the trail."""
         records: list[StepRecord] = []
-        rewards = self._fleet_rewards(chosen, props, reward_cfg)
-        for (s, act, fp), pr, reward in zip(chosen, props, rewards, strict=True):
-            if pr is None:
-                # the pending (if any) was already flushed at _begin_step,
-                # so draining here loses no committed transition
-                s.steps_left = 0
-                with self._stats_lock:
-                    self.n_quarantined += 1
-                continue
-            if reward is _REWARD_FAULT:
-                s.steps_left = 0
-                with self._stats_lock:
-                    self.n_quarantined += 1
-                continue
-            s.current = act.result
-            s.steps_left -= 1
-            done = s.steps_left <= 0
-            if s.best is None or reward > s.best[0]:
-                s.best = (reward, s.current)
-            t = Transition(
-                # the chosen candidate's ALREADY-packed row (chem packed it
-                # once, pack_fps contract) — no per-transition repack
-                state_fp=fp,
-                steps_left_frac=s.steps_left / self.cfg.max_steps,
-                reward=reward,
-                done=done,
-                next_fps=np.zeros((0, FP_BYTES), dtype=np.uint8),
-                next_steps_left_frac=0.0,
-            )
-            if done:
-                buf = buffers[s.worker] if buffers is not None else None
-                if buf is not None:
-                    buf.add(t)               # terminal: no successor needed
-            else:
-                t.next_fps = None            # filled by the next enumerate
-                s.pending = t
-            records.append(StepRecord(
-                slot=s.index, molecule=s.current, reward=reward,
-                done=done, conformer_valid=pr.conformer_valid,
-                bde=pr.bde, ip=pr.ip, worker=s.worker,
-            ))
+        with span("rollout.apply"):
+            rewards = self._fleet_rewards(chosen, props, reward_cfg)
+            for (s, act, fp), pr, reward in zip(chosen, props, rewards, strict=True):
+                if pr is None:
+                    # the pending (if any) was already flushed at _begin_step,
+                    # so draining here loses no committed transition
+                    s.steps_left = 0
+                    with self._stats_lock:
+                        self.n_quarantined += 1
+                    continue
+                if reward is _REWARD_FAULT:
+                    s.steps_left = 0
+                    with self._stats_lock:
+                        self.n_quarantined += 1
+                    continue
+                s.current = act.result
+                s.steps_left -= 1
+                done = s.steps_left <= 0
+                if s.best is None or reward > s.best[0]:
+                    s.best = (reward, s.current)
+                t = Transition(
+                    # the chosen candidate's ALREADY-packed row (chem packed it
+                    # once, pack_fps contract) — no per-transition repack
+                    state_fp=fp,
+                    steps_left_frac=s.steps_left / self.cfg.max_steps,
+                    reward=reward,
+                    done=done,
+                    next_fps=np.zeros((0, FP_BYTES), dtype=np.uint8),
+                    next_steps_left_frac=0.0,
+                )
+                if done:
+                    buf = buffers[s.worker] if buffers is not None else None
+                    if buf is not None:
+                        buf.add(t)               # terminal: no successor needed
+                else:
+                    t.next_fps = None            # filled by the next enumerate
+                    s.pending = t
+                records.append(StepRecord(
+                    slot=s.index, molecule=s.current, reward=reward,
+                    done=done, conformer_valid=pr.conformer_valid,
+                    bde=pr.bde, ip=pr.ip, worker=s.worker,
+                ))
         return records
 
     def _begin_step(self, buffers) -> list[list[Slot]] | None:
@@ -860,25 +873,26 @@ class RolloutEngine:
         This is the CORRECTNESS REFERENCE implementation — strictly
         sequential, no overlap.  ``step_pipelined`` must stay
         transition-identical to it (tests/test_rollout.py)."""
-        policy = as_fleet_policy(policy)
-        buffers = self._pad_buffers(buffers)
-        live_by_worker = self._begin_step(buffers)
-        if live_by_worker is None:
-            return []
+        with span("rollout.step"):
+            policy = as_fleet_policy(policy)
+            buffers = self._pad_buffers(buffers)
+            live_by_worker = self._begin_step(buffers)
+            if live_by_worker is None:
+                return []
 
-        # ---- ONE Q dispatch over all candidates of all workers -------- #
-        q_by_worker, plans = self._dispatch_q(live_by_worker, policy)
+            # ---- ONE Q dispatch over all candidates of all workers -------- #
+            q_by_worker, plans = self._dispatch_q(live_by_worker, policy)
 
-        # ---- per-worker eps-greedy selection --------------------------- #
-        chosen = self._select(live_by_worker, q_by_worker, policy, plans)
+            # ---- per-worker eps-greedy selection --------------------------- #
+            chosen = self._select(live_by_worker, q_by_worker, policy, plans)
 
-        # ---- ONE property batch over the chosen successors fleet-wide -- #
-        props = self._predict_chosen(service, chosen)
+            # ---- ONE property batch over the chosen successors fleet-wide -- #
+            props = self._predict_chosen(service, chosen)
 
-        records = self._apply_step(chosen, props, reward_cfg, buffers)
-        self._enumerate_all()
-        self._flush_dead(buffers)
-        return records
+            records = self._apply_step(chosen, props, reward_cfg, buffers)
+            self._enumerate_all()
+            self._flush_dead(buffers)
+            return records
 
     def _enum_shard(self, mols: Sequence[Molecule]):
         """One pipelined shard, run on a pool thread.  The fault plan's
@@ -893,12 +907,13 @@ class RolloutEngine:
         (``_collect_enum``) can re-run a crashed shard inline."""
         if not pairs:
             return []
-        pool = self._get_pool()
-        mols = [m for _, m in pairs]
-        shard = -(-len(mols) // self._pipeline_threads)
-        return [(pool.submit(self._enum_shard, mols[i:i + shard]),
-                 mols[i:i + shard])
-                for i in range(0, len(mols), shard)]
+        with span("rollout.enumerate"):
+            pool = self._get_pool()
+            mols = [m for _, m in pairs]
+            shard = -(-len(mols) // self._pipeline_threads)
+            return [(pool.submit(self._enum_shard, mols[i:i + shard]),
+                     mols[i:i + shard])
+                    for i in range(0, len(mols), shard)]
 
     def _collect_enum(self, shards) -> list:
         """Supervised harvest of the pipelined shards: a shard whose thread
@@ -941,50 +956,56 @@ class RolloutEngine:
         computes.  Only then does the fetch block.  Per-slot chemistry
         results are composition-independent (pinned by the chem matrix),
         so splitting the enumeration batch changes nothing downstream."""
-        policy = as_fleet_policy(policy)
-        buffers = self._pad_buffers(buffers)
-        live_by_worker = self._begin_step(buffers)
-        if live_by_worker is None:
-            return []
+        with span("rollout.step"):
+            policy = as_fleet_policy(policy)
+            buffers = self._pad_buffers(buffers)
+            live_by_worker = self._begin_step(buffers)
+            if live_by_worker is None:
+                return []
 
-        early: list[tuple[Slot, Molecule]] = []
-        if getattr(policy, "wants_packed_states", False) and \
-                getattr(policy, "async_q", False):
-            bits_pw, frac_pw = self._build_states_packed(live_by_worker)
-            handle = policy.fleet_q_dispatch_packed(bits_pw, frac_pw)
-            plans = self._plan_selection(live_by_worker, policy)
-            early = [(s, s.candidates[p].result)
-                     for w, live in enumerate(live_by_worker)
-                     for s, p in zip(live, plans[w])
-                     if p >= 0 and s.steps_left - 1 > 0]
-            early_futs = self._submit_enum(early)
-            q_by_worker = policy.fleet_q_fetch(handle)
-        else:
-            q_by_worker, plans = self._dispatch_q(live_by_worker, policy)
-            early_futs = []
-        chosen = self._select(live_by_worker, q_by_worker, policy, plans)
+            early: list[tuple[Slot, Molecule]] = []
+            if getattr(policy, "wants_packed_states", False) and \
+                    getattr(policy, "async_q", False):
+                with span("rollout.q_dispatch"):
+                    bits_pw, frac_pw = self._build_states_packed(live_by_worker)
+                    handle = policy.fleet_q_dispatch_packed(bits_pw, frac_pw)
+                plans = self._plan_selection(live_by_worker, policy)
+                early = [(s, s.candidates[p].result)
+                         for w, live in enumerate(live_by_worker)
+                         for s, p in zip(live, plans[w])
+                         if p >= 0 and s.steps_left - 1 > 0]
+                early_futs = self._submit_enum(early)
+                with span("rollout.q_dispatch"):
+                    q_by_worker = policy.fleet_q_fetch(handle)
+            else:
+                q_by_worker, plans = self._dispatch_q(live_by_worker, policy)
+                early_futs = []
+            chosen = self._select(live_by_worker, q_by_worker, policy, plans)
 
-        # slots still alive after this step, in the reference enumeration
-        # order (worker-major, slot order); their successors' candidates are
-        # what the end-of-step enumeration would compute.  Exploring slots
-        # already submitted above (Action.result is memoised, so the chosen
-        # molecule is the very object the early chemistry enumerated).
-        early_slots = {id(s) for s, _ in early}
-        nxt = [(s, a.result) for s, a, _ in chosen
-               if s.steps_left - 1 > 0 and id(s) not in early_slots]
-        futures = self._submit_enum(nxt)
+            # slots still alive after this step, in the reference
+            # enumeration order (worker-major, slot order); their successors'
+            # candidates are what the end-of-step enumeration would compute.
+            # Exploring slots already submitted above (Action.result is
+            # memoised, so the chosen molecule is the very object the early
+            # chemistry enumerated).
+            early_slots = {id(s) for s, _ in early}
+            nxt = [(s, a.result) for s, a, _ in chosen
+                   if s.steps_left - 1 > 0 and id(s) not in early_slots]
+            futures = self._submit_enum(nxt)
 
-        props = self._predict_chosen(service, chosen)
-        records = self._apply_step(chosen, props, reward_cfg, buffers)
+            props = self._predict_chosen(service, chosen)
+            records = self._apply_step(chosen, props, reward_cfg, buffers)
 
-        if early_futs:
-            self._apply_enum([s for s, _ in early],
-                             self._collect_enum(early_futs))
-        if futures:
-            self._apply_enum([s for s, _ in nxt],
-                             self._collect_enum(futures))
-        self._flush_dead(buffers)
-        return records
+            if early_futs or futures:
+                with span("rollout.enumerate"):
+                    if early_futs:
+                        self._apply_enum([s for s, _ in early],
+                                         self._collect_enum(early_futs))
+                    if futures:
+                        self._apply_enum([s for s, _ in nxt],
+                                         self._collect_enum(futures))
+            self._flush_dead(buffers)
+            return records
 
     # ------------------------------------------------------------ #
     def run_episode(

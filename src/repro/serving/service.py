@@ -127,7 +127,11 @@ class _ServePolicy:
         self._table = candidate_capacity_table(n_workers)
         self._cap = 0
         self._buf: np.ndarray | None = None
-        self._apply = jax.jit(network.apply)
+
+        def serve_q_apply(params, x):     # profiled as jit_serve_q_apply
+            return network.apply(params, x)
+
+        self._apply = jax.jit(serve_q_apply)
         self.n_dispatches = 0
 
     def reserve(self, max_candidates: int) -> None:
