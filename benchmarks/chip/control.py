@@ -17,7 +17,8 @@ float32 reference of that seed:
   ``half_batch`` (each update's mean over half its rows), ``no_sync`` (the
   episode sync left out), ``answers_swapped`` (each Q answer, and each
   prediction, handed to the neighbouring row).  A state left unchanged reads
-  1 on ``change_gap`` by definition and needs no run.
+  1 on ``grad_gap`` and ``change_gap`` by definition and needs no run; the
+  control reads 1 on ``bde_gap`` and ``ip_gap``, shares of its own gap.
 
 Each seed's readings are one JSON line in ``<out>/<cell>.jsonl`` and on
 standard output.
@@ -43,15 +44,16 @@ from .harness import log  # noqa: E402
 
 def train_readings(cfg: dict, pseed: int, capture: dict, rng) -> dict:
     prog, aux = C.check_train(cfg, pseed, capture, rng)
-    learn_ref, q_ref, pred_ref = aux["learn_ref"], aux["q_ref"], aux["pred_ref"]
-    mols = aux["mols"]
+    learn_ref, q_ref = aux["learn_ref"], aux["q_ref"]
+    pred_ref, pred_ctl, pp = aux["pred_ref"], aux["pred_ctl"], aux["pred_prog"]
     q_ctl = C.ref_acting(cfg, pseed, capture, "fp8")
     learn_ctl = C.ref_learner(cfg, pseed, capture["batches"], "fp8")
-    pred_ctl = C.ref_predictions(cfg, pseed, mols, "fp8")
+
+    def preds(x: dict) -> dict:
+        return {f"{k}_gap": C.control_share(x[k], pred_ctl[k], pred_ref[k])
+                for k in ("bde", "ip")}
     control = {"q_gap": C.q_gap(q_ctl, q_ref),
-               **C.learner_numbers(learn_ctl, learn_ref, cfg),
-               "bde_gap": C.rel_gap(pred_ctl["bde"], pred_ref["bde"]),
-               "ip_gap": C.rel_gap(pred_ctl["ip"], pred_ref["ip"])}
+               **C.learner_numbers(learn_ctl, learn_ref, cfg), **preds(pred_ctl)}
     B = capture["batches"][0]["state_bits"].shape[1]
     half = C.learner_numbers(
         C.ref_learner(cfg, pseed, capture["batches"], "highest", rows=B // 2),
@@ -59,11 +61,9 @@ def train_readings(cfg: dict, pseed: int, capture: dict, rng) -> dict:
     nosync = C.learner_numbers(
         C.ref_learner(cfg, pseed, capture["batches"], "highest", sync=False),
         learn_ref, cfg)
-    pp = aux["pred_prog"]
     swapped = {"q_gap": C.q_gap([[np.roll(q, 1) for q in d["q"]]
                                  for d in capture["dispatches"]], q_ref),
-               "bde_gap": C.rel_gap(np.roll(pp["bde"], 1), pred_ref["bde"]),
-               "ip_gap": C.rel_gap(np.roll(pp["ip"], 1), pred_ref["ip"])}
+               **preds({k: np.roll(v, 1) for k, v in pp.items()})}
     return {"program": prog, "control": control, "half_batch": half,
             "no_sync": nosync, "answers_swapped": swapped,
             "counts": aux["counts"]}
@@ -84,7 +84,7 @@ def one_seed(cell: dict, seed: int) -> dict:
     run.free()
     rng = np.random.default_rng([int(seed), 0xC4EC])
     out = train_readings(cfg, pseed, capture, rng)
-    out.update(seed=seed, program_s=t_prog,
+    out.update(cell=cell["name"], seed=seed, program_s=t_prog,
                reference_s=time.perf_counter() - t0 - t_prog)
     return out
 

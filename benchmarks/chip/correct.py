@@ -20,7 +20,12 @@ Training (the warm episode, which the window's own call ran):
     change_gap     the parameters' change after the learner call and the
                    episode sync: worst leaf (per worker)
     bde_gap, ip_gap   worst relative gap of the predictors' answers on a
-                   seed-drawn sample of the molecules they were asked about
+                   seed-drawn sample of the molecules they were asked about,
+                   over the same gap of the control on the same sample: an
+                   untrained predictor's weights amplify round-off by a
+                   factor that differs from seed to seed (on a TPU v5e the
+                   control's gap spans 0.017-0.16 over seeds), and the
+                   share of the control's gap does not
 
 A gap of norms is measured against the larger of the reference's norm of
 that leaf and of the median kept leaf.  Leaves whose reference gradient
@@ -106,24 +111,29 @@ def ref_fingerprints(actions: list) -> np.ndarray:
     return np.stack(out) if out else np.zeros((0, 256), np.uint8)
 
 
-def ref_predictions(cfg: dict, pseed: int, mols: list, mode: str) -> dict:
+def ref_predictions(cfg: dict, pseed: int, mols: list, modes: tuple) -> dict:
     """Reference BDE and IP of each molecule (its elements and bonds as
-    the program asked about it), on the reference's own features."""
+    the program asked about it), on the reference's own features, at each
+    matmul precision of ``modes``: ``{mode: {"bde": ..., "ip": ...}}``."""
     import jax.numpy as jnp
 
     p = cfg["predictors"]
     if not mols:
-        return {"bde": np.zeros(0), "ip": np.zeros(0)}
+        return {m: {"bde": np.zeros(0), "ip": np.zeros(0)} for m in modes}
     f = rfeat.features([(m.elements, m.bonds) for m in mols], p["max_atoms"])
     keys = weight_keys(pseed)
     bde_w = rpred.init_bde(keys[1], p["atom_feat"], p["bde_hidden"], p["bde_rounds"])
     ip_w = rpred.init_ip(keys[2], p["atom_feat"] + p["conf_feat"], p["ip_hidden"],
                          p["ip_ensemble"])
     a, mask = jnp.asarray(f["atom_feat"]), jnp.asarray(f["mask"])
-    bde = np.asarray(rpred.bde(bde_w, a, jnp.asarray(f["adj"]), mask, mode=mode))
-    ip = np.asarray(rpred.ip(ip_w, a, jnp.asarray(f["conf_feat"]), mask, mode=mode))
-    return {"bde": np.where(f["has_oh"], bde, np.nan),
-            "ip": np.where(f["conf_valid"] > 0.5, ip, np.nan)}
+    out = {}
+    for mode in modes:
+        bde = np.asarray(rpred.bde(bde_w, a, jnp.asarray(f["adj"]), mask, mode=mode))
+        ip = np.asarray(rpred.ip(ip_w, a, jnp.asarray(f["conf_feat"]), mask,
+                                 mode=mode))
+        out[mode] = {"bde": np.where(f["has_oh"], bde, np.nan),
+                     "ip": np.where(f["conf_valid"] > 0.5, ip, np.nan)}
+    return out
 
 
 def program_predictions(pairs: list) -> dict:
@@ -194,6 +204,15 @@ def rel_gap(prog: np.ndarray, ref: np.ndarray) -> float:
     return float(np.max(np.abs(prog[fr] - ref[fr]) / den))
 
 
+def control_share(prog: np.ndarray, ctl: np.ndarray, ref: np.ndarray) -> float:
+    """``rel_gap`` of the program over ``rel_gap`` of the control, both
+    against the reference on the same sample; 1 for the control itself."""
+    p, c = rel_gap(prog, ref), rel_gap(ctl, ref)
+    if c > 0:
+        return p / c
+    return 0.0 if p == 0 else float("inf")
+
+
 def kept_leaves(ref_grad_norms: np.ndarray, cfg: dict) -> np.ndarray:
     med = float(np.median(ref_grad_norms))
     return ref_grad_norms >= cfg["limits"]["leaf_grad_floor"] * med
@@ -244,16 +263,20 @@ def check_train(cfg: dict, pseed: int, capture: dict,
     fp_prog = np.stack([r for r, _ in rows])
     q_ref = ref_acting(cfg, pseed, capture, "highest")
     learn_ref = ref_learner(cfg, pseed, capture["batches"], "highest")
-    pred_ref = ref_predictions(cfg, pseed, [m for m, _ in pairs], "highest")
+    preds = ref_predictions(cfg, pseed, [m for m, _ in pairs], ("highest", "fp8"))
+    pred_ref, pred_ctl = preds["highest"], preds["fp8"]
     pred_prog = program_predictions(pairs)
     prog_learn = {k: capture[k] for k in ("losses", "grad_norms", "change_norms")}
     numbers = {"q_gap": q_gap([d["q"] for d in capture["dispatches"]], q_ref),
                "fp_rows_wrong": fp_rows_wrong(fp_prog, fp_ref),
                **learner_numbers(prog_learn, learn_ref, cfg),
-               "bde_gap": rel_gap(pred_prog["bde"], pred_ref["bde"]),
-               "ip_gap": rel_gap(pred_prog["ip"], pred_ref["ip"])}
+               **{f"{k}_gap": control_share(pred_prog[k], pred_ctl[k], pred_ref[k])
+                  for k in ("bde", "ip")}}
+    gaps = {f"{k} gap {who}": rel_gap(x[k], pred_ref[k])
+            for k in ("bde", "ip") for who, x in (("program", pred_prog),
+                                                  ("control", pred_ctl))}
     return numbers, {"q_ref": q_ref, "learn_ref": learn_ref, "pred_ref": pred_ref,
-                     "mols": [m for m, _ in pairs], "pred_prog": pred_prog,
+                     "pred_ctl": pred_ctl, "pred_prog": pred_prog,
                      "counts": {"acting rows": sum(r.size for d in q_ref for r in d),
                                 "leaves left out": int(
                                     (~kept_leaves(learn_ref["grad_norms"], cfg)).sum()),
@@ -265,4 +288,4 @@ def check_train(cfg: dict, pseed: int, capture: dict,
                                     kept_leaves(learn_ref["grad_norms"], cfg)),
                                 "fingerprint rows": len(rows),
                                 "learner updates": len(learn_ref["losses"]),
-                                "molecules": len(pairs)}}
+                                "molecules": len(pairs), **gaps}}

@@ -10,7 +10,7 @@ not imported from the program).  From the ``.xplane.pb`` of a traced run:
   window, the numbers the per-layer readers divide;
 * ``idle_by_span(events)``: the first device's idle time in the window
   summed under the innermost host span open at each instant (``events``
-  as ``trace.load`` gives them, the program's spans added to ``host``);
+  as ``trace.load`` gives them);
 * ``window_totals(ctx)``: ``totals`` of the run's own profile, read once
   per file; ``None`` for an untraced run.
 
@@ -73,22 +73,8 @@ def idle_by_span(events: dict) -> dict[str, float]:
     innermost open host span (the shortest of those open; ``"no span"``
     where none is); the values sum to the window less that device's busy
     time."""
-    first = sorted(events["ops"])[0]
-    win = [h for h in events["host"] if h[0] == trace.WINDOW_SPAN]
-    if win:
-        lo, hi = win[0][1], win[0][2]
-    else:
-        allev = [e for evs in events["ops"].values() for e in evs]
-        lo, hi = min(e[1] for e in allev), max(e[2] for e in allev)
-    busy = trace.union(trace.clip([(s, e) for _, s, e in events["ops"][first]],
-                                  lo, hi))
-    idle, cur = [], lo
-    for s, e in busy:
-        if s > cur:
-            idle.append((cur, s))
-        cur = max(cur, e)
-    if hi > cur:
-        idle.append((cur, hi))
+    lo, hi = trace.window(events)
+    idle = trace.idle(events, lo, hi)
     spans = [h for h in events["host"]
              if h[0] != trace.WINDOW_SPAN and h[2] > lo and h[1] < hi]
     cuts = sorted({lo, hi} | {max(h[1], lo) for h in spans}

@@ -6,8 +6,9 @@
 * per program: device seconds and calls of each jitted program, from the
   device's ``XLA Modules`` line, the trailing ``(id)`` of a name dropped;
 * gaps: the idle stretches of the first device inside the window, longest
-  first, each labelled with the innermost benchmark host span open at its
-  middle (what the host was doing while the device waited).
+  first, each labelled with the innermost host span open at its middle,
+  the benchmark's or the program's (what the host was doing while the
+  device waited).
 
 Host and device events share the profiler's clock.
 """
@@ -30,9 +31,13 @@ def _program(name: str) -> str:
 
 def load(path: str | Path) -> dict:
     """Events as (name, start_ns, end_ns): device ops and modules per
-    device plane, and host spans of the benchmark."""
+    device plane, and host spans of the benchmark and of the program
+    (``program_spans.PREFIXES``)."""
     from jax.profiler import ProfileData
 
+    from .program_spans import PREFIXES
+
+    keep = (SPAN_PREFIX,) + PREFIXES
     pd = ProfileData.from_file(str(path))
     ops, modules, host = {}, {}, []
     for plane in pd.planes:
@@ -45,7 +50,7 @@ def load(path: str | Path) -> dict:
             elif m and line.name == MODULES_LINE:
                 modules[int(m.group(1))] = evs
             elif not m:
-                host += [e for e in evs if e[0].startswith(SPAN_PREFIX)]
+                host += [e for e in evs if e[0].startswith(keep)]
     return {"ops": ops, "modules": modules, "host": host}
 
 
@@ -63,17 +68,36 @@ def clip(intervals, lo: float, hi: float):
     return [(max(s, lo), min(e, hi)) for s, e in intervals if e > lo and s < hi]
 
 
+def window(events: dict) -> tuple[int, int]:
+    """``bench.window``'s bounds, or the device operations' where the
+    trace has no such span."""
+    win = [e for e in events["host"] if e[0] == WINDOW_SPAN]
+    if win:
+        return win[0][1], win[0][2]
+    allev = [e for evs in events["ops"].values() for e in evs]
+    return min(e[1] for e in allev), max(e[2] for e in allev)
+
+
+def idle(events: dict, lo: float, hi: float) -> list[tuple[float, float]]:
+    """The first device's idle stretches between ``lo`` and ``hi``, in
+    order."""
+    first = sorted(events["ops"])[0]
+    out, cur = [], lo
+    for s, e in union(clip([(s, e) for _, s, e in events["ops"][first]], lo, hi)):
+        if s > cur:
+            out.append((cur, s))
+        cur = max(cur, e)
+    if hi > cur:
+        out.append((cur, hi))
+    return out
+
+
 def reduce(events: dict, top: int = 10) -> dict:
     """The trace's numbers: ``busy_s``, ``window_s``, ``programs`` {name:
     (seconds, calls)} of the first device, ``gaps`` [(span, seconds)]."""
     if not events["ops"]:
         raise ValueError("the trace holds no device operations")
-    win = [e for e in events["host"] if e[0] == WINDOW_SPAN]
-    if win:
-        lo, hi = win[0][1], win[0][2]
-    else:
-        allev = [e for evs in events["ops"].values() for e in evs]
-        lo, hi = min(e[1] for e in allev), max(e[2] for e in allev)
+    lo, hi = window(events)
     window_ns = hi - lo
     busy = []
     for dev in sorted(events["ops"]):
@@ -86,17 +110,9 @@ def reduce(events: dict, top: int = 10) -> dict:
             p = programs.setdefault(_program(name), [0.0, 0])
             p[0] += (min(e, hi) - max(s, lo)) * 1e-9
             p[1] += 1
-    iv = union(clip([(s, e) for _, s, e in events["ops"][first]], lo, hi))
-    idle, cur = [], lo
-    for s, e in iv:
-        if s > cur:
-            idle.append((cur, s))
-        cur = max(cur, e)
-    if hi > cur:
-        idle.append((cur, hi))
     spans = [e for e in events["host"] if e[0] != WINDOW_SPAN]
     gaps = []
-    for s, e in sorted(idle, key=lambda x: x[0] - x[1])[:top]:
+    for s, e in sorted(idle(events, lo, hi), key=lambda x: x[0] - x[1])[:top]:
         mid = 0.5 * (s + e)
         open_ = [h for h in spans if h[1] <= mid < h[2]]
         label = min(open_, key=lambda h: h[2] - h[1])[0] if open_ else "no span"
