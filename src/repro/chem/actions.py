@@ -132,6 +132,43 @@ class Action:
 _O = ELEMENT_INDEX["O"]
 
 
+def _dfs_tree(bonds: np.ndarray) -> tuple[list[int], ...]:
+    """One iterative DFS from atom 0 over adjacency lists.
+
+    Returns per-atom lists ``(tin, tout, low, par)``: entry time (-1 if not
+    reached), the end of the atom's subtree (its subtree is the atoms with
+    ``tin[c] <= tin < tout[c]``), low-link, and DFS parent (-1 at the root).
+    Tree edge (par[c], c) is a bridge iff ``low[c] > tin[par[c]]``;
+    ``tout[0]`` is the number of atoms reached.
+    """
+    n = bonds.shape[0]
+    rows, cols = np.nonzero(bonds)
+    start = np.searchsorted(rows, np.arange(n + 1)).tolist()
+    nbrs = cols.tolist()
+    tin, tout, low, par = [-1] * n, [0] * n, [0] * n, [-1] * n
+    nxt = start[:n]                     # next neighbour to look at, per atom
+    tin[0] = low[0] = 0
+    t = 1
+    stack = [0]
+    while stack:
+        u = stack[-1]
+        if nxt[u] < start[u + 1]:
+            v = nbrs[nxt[u]]
+            nxt[u] += 1
+            if tin[v] < 0:
+                par[v], tin[v], low[v] = u, t, t
+                t += 1
+                stack.append(v)
+            elif v != par[u]:
+                low[u] = min(low[u], tin[v])
+        else:
+            stack.pop()
+            tout[u] = t
+            if stack:
+                low[stack[-1]] = min(low[stack[-1]], low[u])
+    return tin, tout, low, par
+
+
 def enumerate_actions(
     mol: Molecule,
     *,
@@ -239,23 +276,41 @@ def enumerate_actions(
             # an O at zero free valence gains an H, nothing loses one
             gain_i = (el[di] == _O) & (fv[di] == 0)
             gain_j = (el[dj] == _O) & (fv[dj] == 0)
+            # (a full removal frees `order` valence at both ends too, so the
+            # same flag holds for it unless it drops a fragment)
             oh = (n_oh + gain_i.astype(np.int64) + gain_j.astype(np.int64)) > 0
-            keep_rows = np.ones(di.size, dtype=bool)
             base = sum(len(c) for c in cats)
-            for k in np.nonzero(full)[0]:
-                # full removal may disconnect the graph: materialise (few
-                # candidates, <= one per bonded pair) and check the fragment
-                cand = apply_edit(mol, "bond_delta",
-                                  (int(di[k]), int(dj[k]), -int(delta[k])))
-                if cand.num_atoms == 0:
-                    keep_rows[k] = False
-                    continue
-                frag_results[base + int(np.count_nonzero(keep_rows[:k]))] = cand
-                oh[k] = cand.has_oh_bond()
-            _push(2, di[keep_rows], dj[keep_rows], -delta[keep_rows], oh[keep_rows])
-            if full[keep_rows].any():
-                cat_arr = cats[-1]
-                cat_arr[np.nonzero(full[keep_rows])[0]] = 3
+            tin, tout, low, par = _dfs_tree(mol.bonds)
+            connected = tout[0] == n
+            tin_a = np.asarray(tin)
+            is_o = el == _O
+            n_o = int(is_o.sum())
+            for k in np.nonzero(full)[0].tolist():
+                # full removals are materialised eagerly (<= one per bonded
+                # pair): the fingerprint pass tells bridges from ring bonds
+                # by the result's size
+                i, j, order = int(di[k]), int(dj[k]), int(delta[k])
+                c = j if par[j] == i else i if par[i] == j else -1
+                if not connected:       # several fragments already
+                    cand = apply_edit(mol, "bond_delta", (i, j, -order))
+                    oh[k] = cand.has_oh_bond()
+                elif c >= 0 and low[c] > tin[par[c]]:
+                    # a bridge: c's DFS subtree parts from the side that
+                    # holds atom 0 (the root).  Keep the larger side, then
+                    # the one with more O, then atom 0's (largest_fragment)
+                    sub = (tin_a >= tin[c]) & (tin_a < tout[c])
+                    n_sub = tout[c] - tin[c]
+                    o_sub = int(np.count_nonzero(is_o & sub))
+                    if (n_sub, o_sub) <= (n - n_sub, n_o - o_sub):
+                        sub = ~sub
+                    cand = mol.subgraph(np.flatnonzero(sub))
+                    end = i if sub[i] else j    # gets `order` H back
+                    oh[k] = bool(is_o[end] or np.any(oh_mask & sub))
+                else:
+                    cand = mol.with_bond_delta(i, j, -order)   # a ring bond
+                frag_results[base + k] = cand
+            _push(2, di, dj, -delta, oh)
+            cats[-1][full] = 3
 
     if not cats:
         return []

@@ -160,7 +160,12 @@ class Molecule:
         return -1
 
     def all_pairs_shortest_paths(self) -> np.ndarray:
-        """Hop-distance matrix via repeated BFS.  -1 for disconnected pairs.
+        """Hop-distance matrix, -1 for disconnected pairs.
+
+        One BFS from every source at once, a level at a time: row s of
+        ``frontier`` holds the atoms at distance d from s, and the next
+        level is its product with the adjacency less what was seen (float32
+        so the product runs in BLAS; the counts are small exact integers).
 
         Memoized like :meth:`free_valences` (the action enumerator needs it
         once per enumeration for the ring-size rule, the oracle again for
@@ -170,15 +175,16 @@ class Molecule:
             return self._apsp_cache
         n = self.num_atoms
         out = np.full((n, n), -1, dtype=np.int32)
-        for s in range(n):
-            out[s, s] = 0
-            q = deque([s])
-            while q:
-                u = q.popleft()
-                for v in np.nonzero(self.bonds[u])[0]:
-                    if out[s, v] < 0:
-                        out[s, v] = out[s, u] + 1
-                        q.append(int(v))
+        adj = (self.bonds > 0).astype(np.float32)
+        seen = np.eye(n, dtype=bool)
+        out[seen] = 0
+        frontier = seen
+        for d in range(1, n):
+            frontier = (frontier.astype(np.float32) @ adj > 0) & ~seen
+            if not frontier.any():
+                break
+            out[frontier] = d
+            seen |= frontier
         out.flags.writeable = False
         self._apsp_cache = out
         return out

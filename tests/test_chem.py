@@ -20,6 +20,7 @@ from repro.chem.fingerprint import (
     incremental_fingerprints_grouped, morgan_fingerprint_reference)
 from repro.chem.molecule import iso_hash, refine_invariants
 from repro.chem.smiles import canonical_smiles, from_smiles, to_smiles
+from repro.data.datasets import antioxidant_dataset
 
 PHENOL = "C1=CC=CC=C1O"
 BHT_ISH = "CC1=CC(C)=CC(C)=C1O"
@@ -133,6 +134,70 @@ def test_delta_enumeration_matches_ref(phenol, bht):
                        [_action_signature(a) for a in ref]
 
 
+def _check_against_ref(mol, **kw):
+    """Pins ``enumerate_actions`` to the reference with and without O-H
+    protection, and every full removal materialised at enumeration."""
+    for prot in (True, False):
+        new = enumerate_actions(mol, protect_oh=prot, **kw)
+        assert all(a.materialized for a in new if a.kind == "bond_delta"
+                   and a.detail[2] == -mol.bonds[a.detail[0], a.detail[1]])
+        ref = enumerate_actions_ref(mol, protect_oh=prot, **kw)
+        assert [_action_signature(a) for a in new] == \
+               [_action_signature(a) for a in ref]
+
+
+# One case per branch of the full-removal path: smiles, the removed bond,
+# and the atoms the result keeps (``largest_fragment``'s rule: more atoms,
+# then more O, then the side holding the lower index).
+_REMOVAL_CASES = {
+    "bridge_atom0_falls_away": ("CCCCO", (0, 1), [1, 2, 3, 4]),
+    "bridge_atom0_survives": ("OCCCC", (3, 4), [0, 1, 2, 3]),
+    "tie_size_and_o_lower_index": ("OCCO", (1, 2), [0, 1]),
+    "tie_size_no_o_lower_index": ("CCCC", (1, 2), [0, 1]),
+    "tie_size_more_o_far_side": ("CCCO", (1, 2), [2, 3]),
+    "tie_size_more_o_atom0_side": ("OCCC", (1, 2), [0, 1]),
+    "ring_single": (PHENOL, (0, 1), list(range(7))),
+    "ring_fused": ("C1=CC=C2C=CC=CC2=C1O", (3, 8), list(range(11))),
+    "ring_bridged_bicycle": ("OC1CC2CCC1C2", (1, 2), list(range(8))),
+    "ring_spiro": ("C1CC12CC2O", (0, 1), list(range(6))),
+    "oh_lost_with_fragment": ("CCCCCO", (4, 5), [0, 1, 2, 3, 4]),
+    "oh_given_back_to_end": ("CCCOC", (3, 4), [0, 1, 2, 3]),
+    "parent_in_fragments": ("CCO.CC", (0, 1), [1, 2]),
+}
+
+
+@pytest.mark.parametrize("case", list(_REMOVAL_CASES))
+def test_full_removals_match_ref(case):
+    """Each branch of the full-removal path (bridge sides, the tie rules,
+    ring bonds, the O-H flag) equals the reference, and the case really
+    reaches its branch: the named removal keeps the named atoms, and
+    survives protection iff an O-H does."""
+    smiles, (i, j), kept = _REMOVAL_CASES[case]
+    mol = from_smiles(smiles)
+    _check_against_ref(mol)
+    order = int(mol.bonds[i, j])
+    bonds = mol.bonds.copy()
+    bonds[i, j] = bonds[j, i] = 0
+    want = Molecule(mol.elements[kept], bonds[np.ix_(kept, kept)])
+    for prot in (False, True):
+        hits = [a for a in enumerate_actions(mol, protect_oh=prot)
+                if a.detail == (i, j, -order)]
+        if prot and not want.has_oh_bond():
+            assert not hits
+            continue
+        assert len(hits) == 1
+        assert _action_signature(hits[0])[2:] == \
+               (want.elements.tobytes(), want.bonds.tobytes())
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_delta_enumeration_matches_ref_dataset_walks(seed):
+    """Every molecule of a 10-step walk from the antioxidant dataset, at the
+    configuration's 38-atom cap, enumerates as the reference does."""
+    for mol in _walk(seed, seed=seed):
+        _check_against_ref(mol, max_atoms=38)
+
+
 def test_delta_enumeration_is_lazy(bht):
     """Only fragment-dropping removals may materialise eagerly; every other
     edit builds its Molecule on first ``result`` access (the engine only
@@ -154,6 +219,46 @@ def test_molecule_caches_are_read_only(bht):
         fv[0] = 99
     with pytest.raises(ValueError):
         sp[0, 0] = 99
+
+
+def _walk(start: int, seed: int, steps: int = 10) -> list[Molecule]:
+    """The molecules a random walk over ``enumerate_actions`` visits from
+    molecule ``start`` of ``antioxidant_dataset``, at the configuration's
+    38-atom cap."""
+    rng = np.random.default_rng(seed)
+    mol = antioxidant_dataset(16)[start]
+    out = [mol]
+    for _ in range(steps - 1):
+        acts = enumerate_actions(mol, max_atoms=38)
+        mol = acts[int(rng.integers(0, len(acts)))].result
+        out.append(mol)
+    return out
+
+
+_APSP_CASES = {
+    "phenol": lambda: [from_smiles(PHENOL)],
+    "bht": lambda: [from_smiles(BHT_ISH)],
+    "two_fragments": lambda: [from_smiles("CC.O"),
+                              from_smiles("OC1=CC=CC=C1.CCO")],
+    "empty": lambda: [Molecule.empty()],
+    "single_atom": lambda: [Molecule.from_element("O")],
+    **{f"walk{s}": (lambda s=s: _walk(s, seed=s)) for s in range(4)},
+}
+
+
+@pytest.mark.parametrize("case", list(_APSP_CASES))
+def test_all_pairs_shortest_paths_match_bfs(case):
+    """The level-at-a-time distance matrix equals the per-pair BFS
+    ``shortest_path_length`` everywhere, -1 between fragments included."""
+    for mol in _APSP_CASES[case]():
+        n = mol.num_atoms
+        sp = mol.all_pairs_shortest_paths()
+        want = np.array([[mol.shortest_path_length(i, j) for j in range(n)]
+                         for i in range(n)], dtype=np.int32).reshape(n, n)
+        assert sp.dtype == np.int32
+        assert np.array_equal(sp, want)
+        if case == "two_fragments":
+            assert (sp == -1).any()
 
 
 if HAVE_HYPOTHESIS:
