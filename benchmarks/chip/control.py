@@ -7,18 +7,26 @@ not run this.
         [--out chiprun_out/control]
 
 One process (the chip belongs to it) and, per seed, one program built and
-driven as a run drives it, through the warm episode.  Then, against the
-float32 reference of that seed:
+driven as a run drives it: for training through the warm episode, for
+serving through a window of the workload's ``control_seconds`` at the
+cell's own load.  Then, against the float32 reference of that seed:
 
 * ``program``  the numbers ``correct`` compares (the lower readings);
 * ``control``  the same numbers with the reference computed one precision
   step lower (float8 e4m3 matmul operands) in the program's place;
-* faults planted in the reference put in the program's place:
+* faults planted in the reference put in the program's place.  Training:
   ``half_batch`` (each update's mean over half its rows), ``no_sync`` (the
   episode sync left out), ``answers_swapped`` (each Q answer, and each
   prediction, handed to the neighbouring row).  A state left unchanged reads
   1 on ``grad_gap`` and ``change_gap`` by definition and needs no run; the
   control reads 1 on ``bde_gap`` and ``ip_gap``, shares of its own gap.
+  Serving: ``answers_swapped`` (each slot's Q rows, and each prediction,
+  handed to the neighbouring row), ``fingerprint_flipped`` (one bit of every
+  sampled row), ``prediction_scaled`` (every BDE and IP answer times 1.05),
+  ``choice_shifted`` (each greedy choice one row on).  The control reads 1
+  on ``serve_q_gap``, ``bde_gap`` and ``ip_gap``, shares of its own gap.
+
+The driver's ``readings`` names which of these a cell reads.
 
 Each seed's readings are one JSON line in ``<out>/<cell>.jsonl`` and on
 standard output.
@@ -69,21 +77,50 @@ def train_readings(cfg: dict, pseed: int, capture: dict, rng) -> dict:
             "counts": aux["counts"]}
 
 
-def one_seed(cell: dict, seed: int) -> dict:
-    from .run import driver_class
+def serve_readings(cfg: dict, pseed: int, capture: dict, rng) -> dict:
+    prog, aux = C.check_serve(cfg, pseed, capture, rng)
+    rows, q_ref, q_ctl = aux["rows"], aux["q_ref"], aux["q_ctl"]
+    pred_ref, pred_ctl, pp = aux["pred_ref"], aux["pred_ctl"], aux["pred_prog"]
 
+    def preds(x: dict) -> dict:
+        return {f"{k}_gap": C.control_share(x[k], pred_ctl[k], pred_ref[k])
+                for k in ("bde", "ip")}
+    segments = np.split(rows["q"], np.cumsum(rows["segments"])[:-1]) \
+        if rows["segments"] else []
+    swapped_q = (np.concatenate([np.roll(q, 1) for q in segments])
+                 if segments else rows["q"])[aux["pick"]]
+    flipped = aux["fp_prog"].copy()
+    flipped[:, 0] ^= 0x80
+    return {"program": prog,
+            "control": {"serve_q_gap": C.q_share(q_ctl, q_ctl, q_ref),
+                        **preds(pred_ctl)},
+            "answers_swapped": {"serve_q_gap": C.q_share(swapped_q, q_ctl, q_ref),
+                                **preds({k: np.roll(v, 1) for k, v in pp.items()})},
+            "fingerprint_flipped": {"fp_rows_wrong": C.fp_rows_wrong(
+                flipped, aux["fp_ref"])},
+            "prediction_scaled": preds({k: v * 1.05 for k, v in pp.items()}),
+            "choice_shifted": {"action_wrong": C.action_wrong(capture, shift=1)},
+            "counts": aux["counts"]}
+
+
+def one_seed(cell: dict, seed: int) -> dict:
+    """One seed's readings, through the cell's driver: its set-up, a
+    window of ``control_seconds`` at the cell's load where it has one, and
+    its ``readings``."""
     wl = harness.workload(cell["name"])
     cfg = harness.config(cell["config"])
     spans = harness.Spans()
-    run = driver_class(wl["driver"])(cfg, wl, seed, spans)
+    run = harness.driver_class(wl["driver"])(cfg, wl, seed, spans)
     t0 = time.perf_counter()
     run.build()
     run.warm()
+    if run.control_seconds:
+        run.window(run.control_seconds)
     t_prog = time.perf_counter() - t0
     capture, pseed = run.capture, run.pseed
     run.free()
     rng = np.random.default_rng([int(seed), 0xC4EC])
-    out = train_readings(cfg, pseed, capture, rng)
+    out = run.readings(cfg, pseed, capture, rng)
     out.update(cell=cell["name"], seed=seed, program_s=t_prog,
                reference_s=time.perf_counter() - t0 - t_prog)
     return out

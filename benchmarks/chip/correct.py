@@ -27,6 +27,21 @@ Training (the warm episode, which the window's own call ran):
                    control's gap spans 0.017-0.16 over seeds), and the
                    share of the control's gap does not
 
+Serving (a seeded sample of the window's service steps, ``drivers/serve.py``):
+    fp_rows_wrong  candidate rows, of a seed-drawn sample of the rows the
+                   serving Q dispatch was given, whose fingerprint differs
+                   from the reference's Morgan fingerprint of the candidate,
+                   holds a value other than 0 or 1, or whose steps-left
+                   column differs from the slot's (exact)
+    serve_q_gap    worst |Q - Q_ref| over every dispatched row over the
+                   median |Q_ref|, as a share of the same gap of the control
+                   on the same rows (as the predictor gaps: the gap itself
+                   swings with the seed's weights, the share does not)
+    action_wrong   greedy choices that are not an argmax of the program's own
+                   Q values for the slot (a tie at the top counts as right)
+    bde_gap, ip_gap   as in training, over the molecules the predictors
+                   computed afresh in the sampled steps
+
 A gap of norms is measured against the larger of the reference's norm of
 that leaf and of the median kept leaf.  Leaves whose reference gradient
 is under ``leaf_grad_floor`` times the median leaf's are left out of both
@@ -289,3 +304,119 @@ def check_train(cfg: dict, pseed: int, capture: dict,
                                 "fingerprint rows": len(rows),
                                 "learner updates": len(learn_ref["losses"]),
                                 "molecules": len(pairs), **gaps}}
+
+
+# ------------------------------------------------------------------ #
+# serving: the sampled service steps
+# ------------------------------------------------------------------ #
+def serve_rows(cfg: dict, capture: dict) -> dict:
+    """Every row the sampled steps dispatched: the program's packed bits,
+    steps-left column and Q, whether its fingerprint held only 0s and 1s,
+    its candidate, the steps-left value the slot implies, and the number
+    of rows short of (or past) the slot's candidates."""
+    fp_bits, steps = cfg["qnet"]["in_dim"] - 1, cfg["serve"]["max_steps"]
+    out = {k: [] for k in ("bits", "frac", "binary", "q", "want_frac")}
+    out["cands"], out["segments"], out["mismatch"] = [], [], 0
+    for st in capture["steps"]:
+        if "x" not in st:
+            continue                  # a step that dispatched nothing
+        for x, q, cands, slots in zip(st["x"], st["q"], st["cands"], st["slots"]):
+            want = np.concatenate([np.full(n, (left - 1) / steps, np.float32)
+                                   for left, n in slots]) if slots else \
+                np.zeros(0, np.float32)
+            n = min(len(x), len(cands))
+            out["mismatch"] += abs(len(x) - len(cands))
+            if not n:
+                continue
+            fp = np.asarray(x[:n, :fp_bits])
+            out["bits"].append(np.packbits(fp > 0.5, axis=-1))
+            out["binary"].append(np.all((fp == 0) | (fp == 1), axis=-1))
+            out["frac"].append(np.asarray(x[:n, fp_bits], np.float32))
+            out["q"].append(np.asarray(q[:n], np.float32))
+            out["want_frac"].append(want[:n])
+            out["cands"] += list(cands[:n])
+            out["segments"].append(n)
+    for k in ("bits", "frac", "binary", "q", "want_frac"):
+        out[k] = np.concatenate(out[k]) if out[k] else np.zeros(0)
+    return out
+
+
+def serve_qnet(cfg: dict, pseed: int):
+    """The served Q-network's weights: drawn from the seed's Q key."""
+    return rq.init_qnet(weight_keys(pseed)[0], qnet_sizes(cfg))
+
+
+def q_share(prog: np.ndarray, ctl: np.ndarray, ref: np.ndarray) -> float:
+    """The worst gap over the median |Q_ref| of the program, over the same
+    gap of the control; 1 for the control itself."""
+    if not ref.size:
+        return float("inf")
+    med = _median_abs(ref)
+    p = float(np.max(np.abs(prog - ref))) / med
+    c = float(np.max(np.abs(ctl - ref))) / med
+    if c > 0:
+        return p / c
+    return 0.0 if p == 0 else float("inf")
+
+
+def action_wrong(capture: dict, shift: int = 0) -> int:
+    """Greedy choices (each moved ``shift`` rows on, for a fault) that are
+    not an argmax of the program's Q values of their slot."""
+    bad = 0
+    for st in capture["steps"]:
+        for w, a in st["choices"]:
+            q = np.asarray(st["q"][w], np.float32)
+            a = (a + shift) % max(len(q), 1)
+            bad += int(not (0 <= a < len(q)) or q[a] < q.max())
+    return bad
+
+
+def check_serve(cfg: dict, pseed: int, capture: dict,
+                rng: np.random.Generator) -> tuple[dict, dict]:
+    """Numbers of the served steps against the reference, and what the
+    control needs to reuse."""
+    rows = serve_rows(cfg, capture)
+    pick = sample(list(range(len(rows["cands"]))), FP_SAMPLE, rng)
+    pairs = sample(predicted_pairs(capture["predictions"]), PRED_SAMPLE, rng)
+    fp_ref = ref_fingerprints([rows["cands"][i] for i in pick])
+    fp_prog = rows["bits"][pick] if pick else np.zeros((0, 256), np.uint8)
+    ok_rows = (rows["binary"][pick].astype(bool)
+               & (rows["frac"][pick] == rows["want_frac"][pick])) if pick else \
+        np.zeros(0, bool)
+    # the reference's Q takes its own operands: the sampled rows' reference
+    # fingerprints and the steps left that the client's budget implies
+    params = serve_qnet(cfg, pseed)
+    frac_ref = rows["want_frac"][pick] if pick else np.zeros(0, np.float32)
+    q_ref, q_ctl = (rq.q_rows_blocked(params, fp_ref, frac_ref, mode)
+                    if pick else np.zeros(0, np.float32)
+                    for mode in ("highest", "fp8"))
+    q_prog = rows["q"][pick] if pick else np.zeros(0, np.float32)
+    preds = ref_predictions(cfg, pseed, [m for m, _ in pairs], ("highest", "fp8"))
+    pred_ref, pred_ctl = preds["highest"], preds["fp8"]
+    pred_prog = program_predictions(pairs)
+    wrong = np.any(fp_prog != fp_ref, axis=-1) | ~ok_rows
+    numbers = {"fp_rows_wrong": int(np.count_nonzero(wrong)) + rows["mismatch"],
+               "serve_q_gap": q_share(q_prog, q_ctl, q_ref),
+               "action_wrong": action_wrong(capture),
+               **{f"{k}_gap": control_share(pred_prog[k], pred_ctl[k], pred_ref[k])
+                  for k in ("bde", "ip")}}
+    gaps = {f"{k} gap {who}": rel_gap(x[k], pred_ref[k])
+            for k in ("bde", "ip") for who, x in (("program", pred_prog),
+                                                  ("control", pred_ctl))}
+    med = _median_abs(q_ref) if q_ref.size else 1.0
+    return numbers, {"rows": rows, "pick": pick, "q_prog": q_prog,
+                     "q_ref": q_ref, "q_ctl": q_ctl,
+                     "fp_prog": fp_prog, "fp_ref": fp_ref,
+                     "pred_ref": pred_ref, "pred_ctl": pred_ctl,
+                     "pred_prog": pred_prog,
+                     "counts": {"service steps": len(capture["steps"]),
+                                "Q rows": int(q_ref.size),
+                                "choices": sum(len(s["choices"])
+                                               for s in capture["steps"]),
+                                "fingerprint rows": len(pick),
+                                "molecules": len(pairs),
+                                "Q gap program": float(np.max(np.abs(
+                                    q_prog - q_ref)) / med) if q_ref.size else 0.0,
+                                "Q gap control": float(np.max(np.abs(
+                                    q_ctl - q_ref)) / med) if q_ref.size else 0.0,
+                                **gaps}}

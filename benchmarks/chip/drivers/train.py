@@ -9,6 +9,23 @@ learner call (every update from the initial weights) and the episode
 sync.  The window then calls ``train_episode`` until ``--seconds`` have
 passed; no episode starts after that.
 
+What a driver provides (``harness.driver_class`` finds ``drivers/<name>.py``
+by the workload's ``driver`` and takes its ``Run``):
+
+    Run(cfg, wl, seed, spans)   ``pseed``, the program's seed; ``capture``,
+                                what the timed path produced; ``shapes``,
+                                the number of shapes set-up warmed; ``cap``
+    build(), warm()             set-up
+    counters()                  a snapshot; the window's ``delta`` of two
+                                is in every reader's ``ctx``
+    window(seconds)             ``{"window_s", "attempted", "failed", ...}``,
+                                in ``ctx["w"]``
+    free()                      drop the program's device state
+    check(cfg, pseed, capture, rng) -> (numbers, aux)   against the reference
+    readings(cfg, pseed, capture, rng) -> dict          for ``control.py``
+    control_seconds             the window ``control.py`` drives before
+                                ``readings`` (0: none)
+
 Traffic parameters (``workloads/<cell>.json``, key ``traffic``):
 
     starts            "train_split" (the paper's 256 antioxidant training
@@ -25,11 +42,15 @@ import time
 
 import numpy as np
 
-from .. import harness
+from .. import control, correct, harness
 from . import common
 
 
 class TrainRun:
+    check = staticmethod(correct.check_train)
+    readings = staticmethod(control.train_readings)
+    control_seconds = 0
+
     def __init__(self, cfg: dict, wl: dict, seed: int, spans: harness.Spans):
         self.cfg, self.wl, self.seed, self.spans = cfg, wl, seed, spans
         self.pseed = harness.program_seed(seed)
@@ -227,3 +248,6 @@ class TrainRun:
         self.inner_service = None
         self.service = None
         gc.collect()
+
+
+Run = TrainRun

@@ -7,10 +7,14 @@ metric lives in a file of its own, found by its name:
 
     configs/<config>.json      sizes, precision, limits of the comparison
     workloads/<cell>.json      the traffic mix: which driver, its parameters
-    metrics/<metric>.py        one reader: ``read(ctx) -> float | None``
+    drivers/<driver>.py        its ``Run`` class: builds, warms, measures and
+                               checks one cell (``drivers/train.py`` says what
+                               a driver provides)
+    metrics/<metric>.py        one reader: ``read(ctx) -> float | None``, for
+                               every metric but ``setup_s``
 
-``BENCHMARK.json`` at the checkout's root says which per-layer metrics a
-cell reports.  Nothing here names a cell, a configuration or a metric.
+``BENCHMARK.json`` at the checkout's root says which metrics a cell
+reports.  Nothing here names a cell, a configuration, a driver or a metric.
 """
 
 from __future__ import annotations
@@ -69,15 +73,29 @@ def end_to_end_metrics(spec: dict, cell: str) -> list[dict]:
             if cell in m.get("workloads", [cell])]
 
 
-def metric_reader(name: str):
-    """``metrics/<name>.py``'s ``read`` function."""
-    path = HERE / "metrics" / f"{name}.py"
+def _load(kind: str, name: str, module: str):
+    """Execute ``<kind>/<name>.py`` as the module ``module``."""
+    path = HERE / kind / f"{name}.py"
     if not path.is_file():
-        raise BenchError(f"no reader for per-layer metric {name!r} ({path.name})")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+        raise BenchError(f"no {kind[:-1]} {name!r}: looked for "
+                         f"{path.relative_to(ROOT)}")
+    spec = importlib.util.spec_from_file_location(module, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    """``metrics/<name>.py``'s ``read`` function."""
+    return _load("metrics", name, f"bench_metric_{name}").read
+
+
+def driver_class(name: str):
+    """``drivers/<name>.py``'s ``Run`` class.  The file is a module of
+    this package's ``drivers``, so its relative imports resolve here."""
+    pkg = f"{__package__}.drivers"
+    importlib.import_module(pkg)
+    return _load("drivers", name, f"{pkg}.{name}").Run
 
 
 def add_program_to_path() -> None:

@@ -1,0 +1,6 @@
+"""``device_idle_share.serve`` in the overload cell, where it moves the
+completed rate rather than the tail."""
+
+from chip import harness
+
+read = harness.metric_reader("device_idle_share.serve")

@@ -1,0 +1,6 @@
+"""``slot_occupancy.serve`` in the overload cell, where it moves the
+completed rate rather than the tail."""
+
+from chip import harness
+
+read = harness.metric_reader("slot_occupancy.serve")

@@ -4,14 +4,16 @@
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> \\
         --seconds <s> --trace <0|1>
 
-One process loads the cell's configuration and traffic (found by name
-under ``configs/`` and ``workloads/``), builds the system under test from
-the checkout's ``src/repro`` with weights drawn from ``--seed``, warms
-every shape the window uses, measures for ``--seconds``, checks what the
-timed path produced against the plain reference, and prints one JSON
-object as the last line of standard output.  With ``--trace 1`` the
-window is profiled and the cell's per-layer metrics are reported instead
-of its end-to-end ones.
+One process loads the cell's configuration, traffic and driver (found by
+name under ``configs/``, ``workloads/`` and ``drivers/``), builds the
+system under test from the checkout's ``src/repro`` with weights drawn
+from ``--seed``, warms every shape the window uses, measures for
+``--seconds``, checks what the timed path produced against the plain
+reference (the driver's ``check``), and prints one JSON object as the
+last line of standard output.  Every metric but ``setup_s`` is read by
+its file under ``metrics/``.  With ``--trace 1`` the window is profiled
+and the cell's per-layer metrics are reported instead of its end-to-end
+ones.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits
 non-zero before measuring and prints no result.
@@ -36,16 +38,6 @@ import numpy as np  # noqa: E402
 from . import correct, flops, harness, peaks, trace  # noqa: E402
 from .harness import BenchError, log, metric  # noqa: E402
 
-DRIVERS = ("train",)
-
-
-def driver_class(name: str):
-    if name == "train":
-        from .drivers.train import TrainRun
-        return TrainRun
-    raise BenchError(f"unknown driver {name!r}; known: {DRIVERS}")
-
-
 # ------------------------------------------------------------------ #
 def run_cell(spec: dict, cell: dict, seed: int, seconds: float, traced: bool,
              devices, t_start: float) -> dict:
@@ -57,14 +49,14 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, traced: bool,
     driver = wl["driver"]
     spans = harness.Spans()
     compiles = harness.CompileCounter()
-    run = driver_class(driver)(cfg, wl, seed, spans)
+    run = harness.driver_class(driver)(cfg, wl, seed, spans)
     run.build()
     run.warm()
     before = run.counters()
     c0 = compiles.count
     setup_s = time.perf_counter() - t_start
     log(f"[setup] {cell['name']} seed {seed} ({run.pseed}) set-up "
-        f"{setup_s:.3f} s, {run.shapes} predictor shapes warmed")
+        f"{setup_s:.3f} s, {run.shapes} shapes warmed")
     with harness.maybe_trace(traced) as tdir:
         with spans.span("bench.window"):
             w = run.window(seconds)
@@ -74,7 +66,7 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, traced: bool,
         f"{n_compiles}")
     dev = harness.device_info(devices)
     ctx = {"cell": cell["name"], "driver": driver, "cfg": cfg, "wl": wl,
-           "window_s": w["window_s"], "seconds": seconds,
+           "w": w, "window_s": w["window_s"], "seconds": seconds,
            "delta": harness.delta(after, before),
            "peaks": peaks.peaks_for(dev["kind"]), "flops": flops,
            "trace": None, "cap": run.cap}
@@ -93,7 +85,11 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, traced: bool,
                 result["metrics"][m["name"]] = metric(v, m["unit"])
     else:
         for m in harness.end_to_end_metrics(spec, cell["name"]):
-            v = setup_s if m["name"] == "setup_s" else end_to_end(m["name"], ctx, w)
+            v = setup_s if m["name"] == "setup_s" else \
+                harness.metric_reader(m["name"])(ctx)
+            if v is None:
+                raise BenchError(f"the reader of {m['name']!r} found nothing "
+                                 f"in cell {cell['name']!r}")
             result["metrics"][m["name"]] = metric(v, m["unit"])
     # the comparison, once the window has closed and the program's
     # device state is gone
@@ -102,19 +98,12 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, traced: bool,
     pseed = run.pseed
     run.free()
     t0 = time.perf_counter()
-    numbers, aux = correct.check_train(cfg, pseed, capture, rng)
+    numbers, aux = run.check(cfg, pseed, capture, rng)
     checks = correct.judge(numbers, cfg["limits"]["numbers"])
     log(f"[check] reference comparison {time.perf_counter() - t0:.3f} s over "
         + ", ".join(f"{v} {k}" for k, v in aux["counts"].items()))
     result["correct"] = bool(w["failed"] == 0 and all(c["ok"] for c in checks.values()))
     return result, checks
-
-
-def end_to_end(name: str, ctx: dict, w: dict) -> float:
-    d = ctx["delta"]
-    if name == "train_transitions_per_s":
-        return d["transitions"] / w["window_s"]
-    raise BenchError(f"no end-to-end metric {name!r}")
 
 
 def main(argv=None) -> int:

@@ -19,6 +19,8 @@ def _fails(readings: dict, limits: dict) -> set:
 
 @pytest.mark.parametrize("cell,faults", [
     ("train-w64", ("control", "half_batch", "no_sync", "answers_swapped")),
+    ("serve-w64-steady", ("control", "answers_swapped", "fingerprint_flipped",
+                          "prediction_scaled", "choice_shifted")),
 ])
 def test_control_and_faults_fail_where_the_program_passes(tiny, cell, faults):
     from chip import control, harness

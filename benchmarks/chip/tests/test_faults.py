@@ -5,6 +5,7 @@ the program for each test."""
 
 import time
 
+import pytest
 
 
 def _run(cell: str, seed: int = 17):
@@ -115,3 +116,78 @@ def test_prediction_altered_where_it_is_produced(tiny, monkeypatch):
     result, checks = _run("train-w64")
     assert not result["correct"]
     assert "bde_gap" in _failed(checks)
+
+
+# ------------------------------------------------------------------ #
+# the serving cells: an answer altered where it is produced, and a step
+# that leaves every request where it was
+# ------------------------------------------------------------------ #
+SERVE = "serve-w64-steady"
+
+
+def test_sound_service_is_correct(tiny):
+    result, checks = _run(SERVE)
+    assert result["correct"] and not _failed(checks)
+
+
+def test_serving_q_answers_handed_to_the_neighbouring_row(tiny, monkeypatch):
+    import numpy as np
+
+    from repro.serving.service import _ServePolicy
+
+    orig = _ServePolicy._dispatch
+    monkeypatch.setattr(_ServePolicy, "_dispatch",
+                        lambda self: np.roll(orig(self), 1, axis=1))
+    result, checks = _run(SERVE)
+    assert not result["correct"]
+    assert "serve_q_gap" in _failed(checks)
+
+
+def test_serving_fingerprint_altered_where_it_is_produced(tiny, monkeypatch):
+    _flip_first_bit(monkeypatch)
+    result, checks = _run(SERVE)
+    assert not result["correct"]
+    assert "fp_rows_wrong" in _failed(checks)
+
+
+def test_serving_prediction_altered_where_it_is_produced(tiny, monkeypatch):
+    _alter_bde(monkeypatch)
+    result, checks = _run(SERVE)
+    assert not result["correct"]
+    assert "bde_gap" in _failed(checks)
+
+
+def test_serving_choice_not_the_greedy_one(tiny, monkeypatch):
+    import numpy as np
+
+    from repro.serving.service import MoleculeOptService
+
+    monkeypatch.setattr(MoleculeOptService, "_select_action",
+                        lambda self, q, worker: int(np.argmin(q)))
+    result, checks = _run(SERVE)
+    assert not result["correct"]
+    assert "action_wrong" in _failed(checks)
+
+
+@pytest.mark.parametrize("cell", [SERVE, "serve-w64-overload"])
+def test_service_step_that_leaves_its_state_unchanged(tiny, monkeypatch, cell):
+    from repro.core.rollout import RolloutEngine
+
+    orig = RolloutEngine.step
+    live = {"on": False}
+
+    def frozen(self, *a, **k):
+        return [] if live["on"] else orig(self, *a, **k)
+    monkeypatch.setattr(RolloutEngine, "step", frozen)
+    from chip import harness
+
+    run_cls = harness.driver_class("serve")
+    warm = run_cls.warm
+
+    def warm_then_freeze(self):
+        warm(self)
+        live["on"] = True
+    monkeypatch.setattr(run_cls, "warm", warm_then_freeze)
+    monkeypatch.setattr(harness, "driver_class", lambda name: run_cls)
+    result, checks = _run(cell)
+    assert not result["correct"]

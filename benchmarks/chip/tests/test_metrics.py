@@ -73,3 +73,64 @@ def test_the_new_readers_are_in_the_benchmark():
     for name in EXPECTED:
         assert by_name[name]["workloads"] == ["train-w64", "train-w64-stream"]
         assert by_name[name]["moves"] == "train_transitions_per_s"
+
+
+# ------------------------------------------------------------------ #
+# the serving cells' readers
+# ------------------------------------------------------------------ #
+SERVE_STEPS, SLOTS = 40, 64
+
+SERVE_EXPECTED = {
+    "chem_enumerate_ms": 1e3 * 24.0 / SERVE_STEPS,
+    "chem_fingerprint_ms": 1e3 * 8.0 / SERVE_STEPS,
+    "featurize_ms": 1e3 * 16.0 / SERVE_STEPS,
+    "serve_q_ms": 1e3 * 0.2 / SERVE_STEPS,
+    "device_idle_share": 100.0 * (1 - 0.5 / 25.0),
+    "slot_occupancy": 100.0 * 2048 / (SERVE_STEPS * SLOTS),
+}
+
+
+def _serve_ctx():
+    return {"driver": "serve", "window_s": 25.0,
+            "cfg": {"serve": {"n_slots": SLOTS},
+                    "programs": {"q_dispatch": "jit_serve_q_apply"}},
+            "trace": {"programs": {"jit_serve_q_apply": (0.2, SERVE_STEPS)},
+                      "busy_s": 0.5, "window_s": 25.0},
+            "delta": {"service_steps": SERVE_STEPS, "bound": 2048}}
+
+
+@pytest.mark.parametrize("cell", ["serve", "overload"])
+@pytest.mark.parametrize("name", sorted(SERVE_EXPECTED))
+def test_serve_reader_gives_the_number(name, cell, spans_read):
+    read = harness.metric_reader(f"{name}.{cell}")
+    assert read(_serve_ctx()) == pytest.approx(SERVE_EXPECTED[name])
+
+
+@pytest.mark.parametrize("name", sorted(SERVE_EXPECTED))
+def test_serve_reader_gives_nothing_without_its_input(name, spans_read):
+    read = harness.metric_reader(f"{name}.serve")
+    train = _ctx()
+    train["trace"].update(busy_s=1.0, window_s=50.0)
+    assert read(train) is None                         # a training cell
+    idle = _serve_ctx()
+    idle["delta"]["service_steps"] = 0
+    if name != "device_idle_share":
+        assert read(idle) is None
+    if name != "slot_occupancy":
+        untraced = _serve_ctx()
+        untraced["trace"] = None
+        assert read(untraced) is None
+
+
+def test_the_serving_readers_have_their_entries():
+    import json
+
+    import tiny
+
+    spec = json.loads(tiny.SERVING.read_text())
+    by_name = {m["name"]: m for m in spec["per_layer"]}
+    for name in SERVE_EXPECTED:
+        assert by_name[f"{name}.serve"]["workloads"] == ["serve-w64-steady"]
+        assert by_name[f"{name}.serve"]["moves"] == "serve_p95_latency_s"
+        assert by_name[f"{name}.overload"]["workloads"] == ["serve-w64-overload"]
+        assert by_name[f"{name}.overload"]["moves"] == "serve_completed_rps"
